@@ -7,22 +7,20 @@ deployment format buys), kernel-launch counts for the ahead-of-time plan
 path vs the per-stripe path, and interpret-mode correctness timing.
 
 Rows cover decode-shaped matmuls (M=1 single-token, M=8 a decode batch)
-next to the prefill-ish M=64, and A/B the two activation-fetch paths of
-the prepared matmul: the pre-fold XLA gather (gather="xla") vs the
-in-kernel fetch (gather="kernel" — aligned block reads for integer
-bit-widths, in-kernel takes for mixed-precision plans; DESIGN.md §9).
-The opt-in int8 activation path is timed alongside with its measured
-error against the f32 reference checked under the documented bound.
+next to the prefill-ish M=64: the prepared matmul (aligned block reads
+for integer bit-widths, one XLA take per permuted group for
+mixed-precision plans; DESIGN.md §9) against the opt-in int8 activation
+path, whose measured error against the f32 reference is checked under
+the documented bound.
 A small bk/bn sweep at 4 bits chases the near-parity prepared result
 PR 1 left on the table.
 
 `kernel_bench()` writes BENCH_kernel.json at the repo root so the
 prepared-vs-unprepared perf trajectory is tracked across PRs.  `--smoke`
-(the CI step) shrinks reps and SELF-ASSERTS the structural claims:
-prepared runs at or under the unprepared time, and the in-kernel gather
-at or under the XLA gather's time, at every bit-width (a 25% tolerance
-plus a 4x-reps re-measure absorbs shared-box noise; the interleaved
-min-of-N sampling cancels drift).
+(the CI step) shrinks reps and SELF-ASSERTS the structural claim:
+prepared runs at or under the unprepared time at every bit-width (a 25%
+tolerance plus a 4x-reps re-measure absorbs shared-box noise; the
+interleaved min-of-N sampling cancels drift).
 
   PYTHONPATH=src python -m benchmarks.kernel_bench [--smoke] [--out PATH]
 """
@@ -41,6 +39,7 @@ from repro.core import APConfig, CLAQConfig, ORConfig, quantize_matrix
 from repro.kernels import dequant_matmul as dm
 from repro.kernels import ops, ref as ref_lib
 from repro.kernels.plan import prepare_for_inference
+from repro.launch.compile_cache import use_compile_cache
 
 _BENCH_JSON = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -119,35 +118,31 @@ def kernel_bench(out_json: str = _BENCH_JSON, smoke: bool = False):
         ratio = dense_bytes / q_bytes
 
         def run_unprep(a, q=qt):
-            return ops.qmatmul(a, q, use_kernel=True, interpret=True)
+            return ops.qmatmul(a, q, use_kernel=True)
 
-        def run_xla_gather(a, q=pqt):
-            return ops.prepared_qmatmul(a, q, gather="xla")
-
-        def run_kernel_gather(a, q=pqt):
-            return ops.prepared_qmatmul(a, q, gather="kernel")
+        def run_prepared(a, q=pqt):
+            return ops.prepared_qmatmul(a, q)
 
         def run_int8(a, q=pqt):
-            return ops.prepared_qmatmul(a, q, gather="kernel",
-                                        act_dtype="int8")
+            return ops.prepared_qmatmul(a, q, act_dtype="int8")
 
         x_big = xs[max(ms)]
         c0 = dm.launch_count
         run_unprep(x_big)
         launches_unprep = dm.launch_count - c0
         c0 = dm.launch_count
-        run_kernel_gather(x_big)
+        run_prepared(x_big)
         launches_prep = dm.launch_count - c0
 
         # prepared-vs-unprepared continuity row (PR 1's fusion claim) at
-        # the largest M, on the in-kernel-gather path serving now
-        us_unprep, us_prep = _time_pair(run_unprep, run_kernel_gather,
+        # the largest M
+        us_unprep, us_prep = _time_pair(run_unprep, run_prepared,
                                         x_big, reps=reps)
-        err = float(jnp.max(jnp.abs(run_kernel_gather(x_big)
+        err = float(jnp.max(jnp.abs(run_prepared(x_big)
                                     - ref_lib.ref_qmatmul(x_big, qt))))
         if smoke:
             us_prep, us_unprep = _assert_not_slower(
-                run_kernel_gather, run_unprep, x_big, us_prep, us_unprep,
+                run_prepared, run_unprep, x_big, us_prep, us_unprep,
                 reps, f"{bits}-bit prepared-vs-unprepared")
 
         key = str(bits)
@@ -168,35 +163,18 @@ def kernel_bench(out_json: str = _BENCH_JSON, smoke: bool = False):
         rows.append((f"kernel/dequant_matmul_{key}bit_interp_prepared",
                      us_prep, f"launches={launches_prep};max_err={err:.2e}"))
 
-        # decode-shaped rows: in-kernel gather vs XLA gather, + int8
+        # decode-shaped rows: prepared vs int8 activations
         Wd = qt.dequantize()
         for m in ms:
             x = xs[m]
             # recorded figures sample at 4x the smoke budget, in TWO
-            # temporally separated passes min-combined — unconditional, so
-            # no result-conditioned re-roll can bias the published A/B
-            # (smoke keeps the small budget; its asserts escalate
-            # themselves on a miss)
-            us_xla, us_ker = _time_pair(run_xla_gather, run_kernel_gather,
-                                        x, reps=reps if smoke else 4 * reps)
-            if smoke:
-                us_i8 = min(_sample(run_int8, x) for _ in range(reps))
-            else:
-                a2, k2 = _time_pair(run_xla_gather, run_kernel_gather,
-                                    x, reps=4 * reps)
-                us_xla, us_ker = min(us_xla, a2), min(us_ker, k2)
-                # int8 rides the same protocol: interleaved against the
-                # kernel-gather baseline (drift-cancelled), two separated
-                # passes min-combined; the companion sample is discarded
-                # so the published A/B pair stays symmetric
-                _, i1 = _time_pair(run_kernel_gather, run_int8, x,
-                                   reps=2 * reps)
-                _, i2 = _time_pair(run_kernel_gather, run_int8, x,
-                                   reps=2 * reps)
-                us_i8 = min(i1, i2)
-            assert np.array_equal(np.asarray(run_kernel_gather(x)),
-                                  np.asarray(run_xla_gather(x))), \
-                f"{bits}-bit m={m}: gather paths diverged (must be bitwise)"
+            # temporally separated passes min-combined (smoke keeps the
+            # small budget)
+            us_prep_m, us_i8 = _time_pair(run_prepared, run_int8, x,
+                                          reps=reps if smoke else 4 * reps)
+            if not smoke:
+                p2, i2 = _time_pair(run_prepared, run_int8, x, reps=4 * reps)
+                us_prep_m, us_i8 = min(us_prep_m, p2), min(us_i8, i2)
             y_ref = ref_lib.ref_qmatmul(x, qt)
             err_el = jnp.abs(run_int8(x) - y_ref)
             bound_el = ref_lib.ref_act_int8_bound(x, Wd)
@@ -207,23 +185,14 @@ def kernel_bench(out_json: str = _BENCH_JSON, smoke: bool = False):
                 (bits, m, float(jnp.max(err_el - bound_el)))
             i8_err = float(jnp.max(err_el))
             i8_bound = float(jnp.max(bound_el))
-            if smoke:
-                us_ker, us_xla = _assert_not_slower(
-                    run_kernel_gather, run_xla_gather, x, us_ker, us_xla,
-                    reps, f"{bits}-bit m={m} in-kernel-vs-XLA gather")
             results[key][f"m{m}"] = {
-                "interp_us_xla_gather": us_xla,
-                "interp_us_kernel_gather": us_ker,
+                "interp_us_prepared": us_prep_m,
                 "interp_us_int8": us_i8,
                 "int8_max_err": i8_err,
                 "int8_err_bound": i8_bound,
             }
-            rows.append((f"kernel/dequant_matmul_{key}bit_m{m}_xla_gather",
-                         us_xla, "prefold_take"))
-            rows.append((f"kernel/dequant_matmul_{key}bit_m{m}_kernel_gather",
-                         us_ker,
-                         f"speedup={us_xla / max(us_ker, 1e-9):.2f}x;"
-                         f"gather_free={pqt.x_gather_free}"))
+            rows.append((f"kernel/dequant_matmul_{key}bit_m{m}_prepared",
+                         us_prep_m, f"gather_free={pqt.x_gather_free}"))
             rows.append((f"kernel/dequant_matmul_{key}bit_m{m}_act_int8",
                          us_i8, f"max_err={i8_err:.2e};bound={i8_bound:.2e}"))
 
@@ -238,7 +207,7 @@ def kernel_bench(out_json: str = _BENCH_JSON, smoke: bool = False):
             p = prepare_for_inference(qt4, bn=bn, bk=bk)
 
             def run(a, q=p):
-                return ops.prepared_qmatmul(a, q, gather="kernel")
+                return ops.prepared_qmatmul(a, q)
 
             run(x8)
             us = min(_sample(run, x8) for _ in range(reps))
@@ -285,10 +254,10 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="CI mode: fewer reps, decode shapes only, and "
                          "self-assert that prepared runs no slower than "
-                         "unprepared and the in-kernel gather no slower "
-                         "than the XLA gather, at every bit-width")
+                         "unprepared at every bit-width")
     ap.add_argument("--out", default=_BENCH_JSON)
     args = ap.parse_args()
+    use_compile_cache()
     kernel_bench(out_json=args.out, smoke=args.smoke)
 
 

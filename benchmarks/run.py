@@ -17,6 +17,9 @@ def main() -> None:
                     help="comma list, e.g. table1,kernel")
     args, _ = ap.parse_known_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import tables
     from .kernel_bench import kernel_bench, roofline_rows
     from .serve_bench import serve_bench

@@ -62,6 +62,7 @@ import time
 import numpy as np
 
 from repro.core import APConfig, CLAQConfig, ORConfig, draft_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.quantize import quantize_model_params
 from repro.serve import (AdmissionRejected, FaultInjector, Replayer,
                          RetryPolicy, ServingEngine, SpecConfig, StepClock,
@@ -610,6 +611,7 @@ def main():
                          "zero hangs and every request terminal)")
     ap.add_argument("--out", default=_BENCH_JSON)
     args = ap.parse_args()
+    use_compile_cache()
     serve_bench(out_json=args.out, smoke=args.smoke,
                 faults_only=args.inject_faults)
 

@@ -3,10 +3,10 @@ compiled-artifact and trace rules consume, plus ``verify_engine`` — the
 one-call gate behind ``ServingEngine(verify_contracts=True)``.
 
 The expensive piece is the HLO: ``engine_context`` AOT-lowers the
-engine's decode jit under kernel mode (interpret=True so the pallas
-kernels lower off-accelerator) and, for the gather-parity rule, builds a
-*dense twin* — the same engine over the dequantized weights — whose
-compiled decode is the gather baseline.  Everything else (plan stats,
+engine's decode jit under kernel mode (compiled on a TPU, interpreted
+elsewhere) and, for the gather-parity rule, builds a *dense twin* — the
+same engine over the dequantized weights — whose compiled decode is the
+gather baseline.  Everything else (plan stats,
 shard thresholds, pool-slice limits, compile budgets) is cheap host-side
 tree walking.
 """
@@ -180,10 +180,10 @@ def _cache_leaf_bytes(engine) -> int:
     return best
 
 
-def lowered_decode_text(engine, interpret: bool = True) -> str:
+def lowered_decode_text(engine) -> str:
     """Compiled HLO of the engine's decode step under kernel mode (the
     deployment path the gather/dtype contracts guard)."""
-    with nn.quant_mode("kernel", interpret=interpret):
+    with nn.quant_mode("kernel"):
         return engine.lower_decode().compile().as_text()
 
 
@@ -194,13 +194,12 @@ def _mesh_model_parts(engine) -> int:
 
 
 def engine_context(engine, dense_engine=None, *,
-                   interpret: bool = True,
                    collective_budget_bytes: Optional[int] = None,
                    donation_expected: bool = False) -> Dict[str, Any]:
     """Build the full rule context from a live engine (and, optionally, a
     dense twin engine supplying the gather baseline)."""
     ctx: Dict[str, Any] = {
-        "hlo": {"decode": lowered_decode_text(engine, interpret)},
+        "hlo": {"decode": lowered_decode_text(engine)},
         "plan": plan_stats(engine.params, n_slots=engine.n_slots),
         "cache_leaf_bytes": _cache_leaf_bytes(engine),
         "donation_expected": donation_expected,
@@ -218,7 +217,7 @@ def engine_context(engine, dense_engine=None, *,
         ctx["pool_slice_elems"] = pool
     if dense_engine is not None:
         ctx["dense_hlo"] = {
-            "decode": lowered_decode_text(dense_engine, interpret)}
+            "decode": lowered_decode_text(dense_engine)}
     return ctx
 
 
@@ -240,7 +239,6 @@ def dense_twin_engine(engine):
 def verify_engine(engine, dense_engine=None, *,
                   rules: Optional[List[Rule]] = None,
                   with_baseline: bool = True,
-                  interpret: bool = True,
                   raise_on_error: bool = True,
                   subject: str = "engine") -> Report:
     """Run the compiled-artifact + trace rules against a live engine;
@@ -251,7 +249,7 @@ def verify_engine(engine, dense_engine=None, *,
     if (dense_engine is None and with_baseline
             and plan_stats(engine.params)["has_plans"]):
         dense_engine = dense_twin_engine(engine)
-    ctx = engine_context(engine, dense_engine, interpret=interpret)
+    ctx = engine_context(engine, dense_engine)
     report = run_rules(rules if rules is not None
                        else list(HLO_RULES) + list(TRACE_RULES),
                        ctx, subject=subject)

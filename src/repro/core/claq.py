@@ -169,8 +169,6 @@ def _quantize_rowsharded(Wp, U, bits_p, res_p, kwargs, mesh, shard_axis):
     """Run the GPTQ loop with matrix rows sharded over `shard_axis`."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import shard_map
-
     def body(Wl, Ul, bl, rl):
         return gptq.gptq_quantize_matrix(
             Wl, Ul, bl, rl, axis_name=shard_axis, **kwargs)
@@ -178,7 +176,7 @@ def _quantize_rowsharded(Wp, U, bits_p, res_p, kwargs, mesh, shard_axis):
     out_specs = gptq.QuantizeResult(
         Q=P(shard_axis, None), codes=P(shard_axis, None),
         codebooks=P(None, None), reserved=P(shard_axis, None))
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(shard_axis, None), P(None, None), P(None), P(shard_axis, None)),
         out_specs=out_specs,

@@ -1,1 +1,1 @@
-from . import compat, context, hlo_analysis, sharding  # noqa: F401
+from . import context, hlo_analysis, sharding  # noqa: F401

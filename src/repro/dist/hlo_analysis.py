@@ -183,27 +183,58 @@ def _parse_computations(text: str) -> Dict[str, List[_Instr]]:
     return comps
 
 
-def _dot_flops(line: str) -> float:
+def _shapes_by_name(comps: Dict[str, List[_Instr]]) -> Dict[str, str]:
+    """Instruction name -> dims of its (array) result type.  HLO names are
+    unique module-wide, so one map resolves operands in any computation."""
+    out: Dict[str, str] = {}
+    for comp in comps.values():
+        for ins in comp:
+            rhs = ins.line.split("=", 1)[1].lstrip()
+            m = _SHAPE_RE.match(rhs)
+            if m:
+                out[ins.name] = m.group(2)
+    return out
+
+
+def _operand_dims(rhs: str, op: str, shapes: Dict[str, str]) -> List[str]:
+    """Dims of each operand of `op(...)`, resolved by instruction name.
+    Newer XLA prints operands as bare names (`dot(%a, %b)`); older prints
+    put the type inline (`dot(f32[8,64]{1,0} %a, ...)`), read as is."""
+    start = rhs.find(op + "(")
+    if start < 0:
+        return []
+    region = _tuple_region(rhs[start + len(op):])[1:-1]
+    dims = []
+    for arg in region.split(", "):
+        inline = _SHAPE_RE.search(arg)
+        name = arg.split()[-1].lstrip("%") if arg.split() else ""
+        dims.append(inline.group(2) if inline else shapes.get(name, ""))
+    return dims
+
+
+def _dot_flops(line: str, shapes: Dict[str, str]) -> float:
     """2 * out_elems * contracted_size for dot/convolution lines."""
     rhs = line.split("=", 1)[1]
-    shapes = _SHAPE_RE.findall(rhs)
-    if not shapes:
+    res = _SHAPE_RE.search(rhs)
+    if not res:
         return 0.0
-    out_elems = _shape_elems(shapes[0][1])
-    if "convolution" in rhs:
+    out_elems = _shape_elems(res.group(2))
+    conv = "convolution" in rhs
+    operands = _operand_dims(rhs, "convolution" if conv else "dot", shapes)
+    if conv:
         # rhs operand (the kernel) fully contracts except its output-feature
         # dim; a robust proxy: 2 * out * (kernel_elems / out_features).
-        if len(shapes) >= 3:
-            out_feat = max(int(d) for d in shapes[0][1].split(",") if d) \
-                if shapes[0][1] else 1
-            k_elems = _shape_elems(shapes[2][1])
+        if len(operands) >= 2 and operands[1]:
+            out_feat = max(int(d) for d in res.group(2).split(",") if d) \
+                if res.group(2) else 1
+            k_elems = _shape_elems(operands[1])
             return 2.0 * out_elems * max(k_elems // max(out_feat, 1), 1)
         return 2.0 * out_elems
     m = re.search(r"lhs_contracting_dims=\{([0-9,]*)\}", rhs)
     if not m:
         return 2.0 * out_elems
-    lhs_dims = [int(d) for d in shapes[1][1].split(",") if d] \
-        if len(shapes) > 1 else []
+    lhs_dims = [int(d) for d in operands[0].split(",") if d] \
+        if operands else []
     contracted = 1
     for d in (int(x) for x in m.group(1).split(",") if x):
         if d < len(lhs_dims):
@@ -412,6 +443,7 @@ def donation_aliases(text: str):
 def analyze_hlo(text: str) -> Dict[str, float]:
     comps = _parse_computations(text)
     entry = comps.get("__entry__", [])
+    shapes = _shapes_by_name(comps)
 
     def walk(comp: List[_Instr]) -> Dict[str, float]:
         acc: Dict[str, float] = {"flops": 0.0, "elementwise_flops": 0.0,
@@ -430,7 +462,7 @@ def analyze_hlo(text: str) -> Dict[str, float]:
                     cond = cm.group(1) if cm else cond
                     mult = _derive_trip_count(comps, comp, ins.line, cond)
             if ins.op in ("dot", "convolution"):
-                acc["flops"] += _dot_flops(ins.line)
+                acc["flops"] += _dot_flops(ins.line, shapes)
             cost = _ELEMWISE_COST.get(ins.op)
             if cost is not None:
                 acc["elementwise_flops"] += cost * _result_elems(ins.line)

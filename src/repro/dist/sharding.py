@@ -23,6 +23,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
@@ -54,7 +55,7 @@ def _shardable(dim: int, size: int) -> bool:
 
 def _quantized_types():
     """Lazy import: dist must stay importable before kernels/core finish
-    initializing (core pulls dist.compat for the sharded quantizer)."""
+    initializing."""
     from repro.core.quantized import QuantizedTensor
     from repro.kernels.plan import PreparedQuantizedTensor
     return QuantizedTensor, PreparedQuantizedTensor
@@ -64,7 +65,7 @@ def _quantized_types():
 # per-leaf rule must never invent a spec for these (see spec_for_quantized)
 _QUANT_LEAF_MARKERS = (".groups[", ".planes[", ".gather_idx", ".codebook",
                       ".out_idx", ".out_val", ".stripes[", ".col_perm",
-                      ".out_count", ".packed", ".x_idx")
+                      ".out_count", ".packed")
 
 
 def spec_for_quantized(q, ax: MeshAxes):
@@ -76,9 +77,8 @@ def spec_for_quantized(q, ax: MeshAxes):
         multiple of the 32-row word, so a bn-aligned split is word-aligned
         and every shard keeps whole (bn, bk) tiles);
       * `codebook` / `out_idx` / `out_val` are K-indexed (and outlier idx
-        *values* are global row numbers), `gather_idx` / the per-group
-        `x_idx` block tables index the activation's K axis — all
-        replicated;
+        *values* are global row numbers), `gather_idx` indexes the
+        activation's K axis — all replicated;
       * guarded by `shards_whole_tiles(model_size)`: when the tile count
         does not divide, the WHOLE unit stays replicated — never torn;
       * stacked (L, ...) / (L, E, ...) leaves (launch.quantize stacks
@@ -112,6 +112,46 @@ def spec_for_quantized(q, ax: MeshAxes):
     if not isinstance(q, (QuantizedTensor, PreparedQuantizedTensor)):
         raise TypeError(f"not a quantized unit: {type(q)}")
     return jax.tree_util.tree_map(lambda _: PartitionSpec(), q)
+
+
+def sharded_qmatmul(x, pqt, mesh, *, act_dtype=None):
+    """The fused kernel on a device mesh.  XLA cannot partition a Mosaic
+    kernel, so each device runs `kernels.ops.qmatmul` under shard_map on
+    its own share: `pqt`'s N tiles as `spec_for_quantized` splits them
+    over "model" (a unit that does not split whole stays replicated) and
+    x's leading rows over "dp" where they divide.  Outlier row ids are
+    global, so each shard shifts them into its local rows; ids of other
+    shards then match no local row.  Every output element is the same
+    contraction as on one device, so the result is bitwise the unsharded
+    kernel's."""
+    import dataclasses
+
+    from repro.kernels import ops as kops
+
+    ax = MeshAxes(mesh)
+    split = ax.model_size > 1 and pqt.shards_whole_tiles(ax.model_size)
+    n_local = pqt.n_padded // ax.model_size if split else pqt.n_padded
+    dp = ax.dp if x.ndim > 1 and x.shape[0] % ax.dp_size == 0 else None
+    mid = [None] * (x.ndim - 2)
+
+    def body(xl, p):
+        if split:
+            off = jax.lax.axis_index(ax.model_axes) * n_local
+            p = dataclasses.replace(
+                p, shape=(n_local, p.cols), n_padded=n_local,
+                groups=tuple(
+                    g if g.out_idx is None else dataclasses.replace(
+                        g, out_idx=jnp.where(g.out_idx >= 0,
+                                             g.out_idx - off, -1))
+                    for g in p.groups))
+        return kops.qmatmul(xl, p, use_kernel=True, act_dtype=act_dtype)
+
+    y = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(PartitionSpec(dp, *mid, None), spec_for_quantized(pqt, ax)),
+        out_specs=PartitionSpec(dp, *mid, ax.model if split else None),
+        check_vma=False)(x, pqt)
+    return y[..., :pqt.rows]
 
 
 def spec_for_param(name: str, shape, cfg, ax: MeshAxes) -> PartitionSpec:

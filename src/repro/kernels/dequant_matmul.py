@@ -2,7 +2,8 @@
 
 Computes  y = x @ W^T  where W (N=out, K=in) is stored as:
   * packed code planes (uint32 words along the N axis, one stream/column),
-  * a per-column codebook (K, 2**bits),
+  * a per-column codebook stored level-major, (2**bits, K): one centroid
+    level per sublane row, columns along lanes,
   * structured outliers: up to `k_out` (row-index, fp-value) pairs per
     column overriding the dequantized value (Outlier Reservation, §3.4).
 
@@ -21,9 +22,9 @@ resident in VMEM across the K sweep (revisited accumulation).
 
 Activation fetch (`x_mode`, DESIGN.md §9) — how each (bm, bk) x tile
 reaches the MXU:
-  * "blocked": x arrives pre-gathered and K-padded by the caller (the
-    legacy XLA-gather path and the per-stripe unprepared dispatch); the
-    tile is a plain (i, k) block.
+  * "blocked": x arrives gathered into the group's fused K order and
+    K-padded by the caller (permuted groups and the per-stripe unprepared
+    dispatch); the tile is a plain (i, k) block.
   * "aligned": x is the RAW activation matrix; the plan proved the group's
     fused K order IS original column order (single-bit-width tensors —
     `build_quantized_tensor` emits an identity permutation), so the tile
@@ -31,13 +32,9 @@ reaches the MXU:
     `k_cols` is masked to zero in-kernel (the tail's codebooks/outliers
     are zero/-1, but interpret-mode Pallas pads out-of-bounds blocks with
     NaN, and NaN * 0 would poison the accumulator).
-  * "gathered": x is the raw matrix, VMEM-resident as one (bm, K) block
-    pinned at (i, 0) across the whole (N, K) sweep, plus a per-bk-block
-    int32 index table (the plan's `gather_idx` reshaped); the kernel takes
-    the tile's columns out of the resident block (on TPU a VMEM-local
-    dynamic gather along lanes; never an HBM gather) and masks index
-    `cols` (the fill slot) to 0.0 — bitwise the same tile the XLA
-    `jnp.take(..., mode="fill")` used to build.
+  A permuted group's columns are not taken inside the kernel: Mosaic has
+  no lane gather over a VMEM-resident x block, so kernels/ops.py takes
+  them with one XLA gather per group and launches "blocked".
 
 Per-token int8 activations ride any mode: x may be int8 (cast to the
 compute dtype after the fetch) with an optional (M, 1) f32 `x_scale`
@@ -57,14 +54,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-# jax<0.5 names the Pallas TPU params class TPUCompilerParams; newer jax
-# renamed it back to CompilerParams.  Resolve whichever exists.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "TPUCompilerParams", None) \
-    or getattr(pltpu, "CompilerParams")
-
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
+
+
+def _platform() -> str:
+    """The backend quantized matmuls are traced for (tests steer it)."""
+    return jax.default_backend()
 
 
 def _unpack_tile(words, width: int, bn: int):
@@ -76,16 +73,20 @@ def _unpack_tile(words, width: int, bn: int):
     return ((rep >> shift) & mask).astype(jnp.int32)
 
 
-def _dequant_tile(codes, cb, n_levels: int, compute_dtype):
-    """codes (bn, bk) + cb (bk, n_levels) -> W tile (bn, bk).
+def _dequant_tile(codes, cb, n_levels: int):
+    """codes (bn, bk) + cb (n_levels, bk) -> f32 W tile (bn, bk).
 
     n_levels-way select-accumulate: for <=16 centroids this is a handful of
     vectorized VPU ops per element — cheaper and more TPU-natural than a
-    gather from VMEM.
+    gather from VMEM.  Level c is the sublane row cb[c] broadcast down the
+    tile (a lane-axis index into the codebook would be a lane gather, which
+    Mosaic rejects).  The chain selects in f32 whatever the compute dtype:
+    Mosaic cannot relayout the i1 mask of a 16-bit select, and selecting
+    before the single cast yields the same bits as selecting cast values.
     """
-    w = jnp.zeros(codes.shape, compute_dtype)
+    w = jnp.zeros(codes.shape, jnp.float32)
     for c in range(n_levels):
-        w = jnp.where(codes == c, cb[None, :, c].astype(compute_dtype), w)
+        w = jnp.where(codes == c, cb[c:c + 1, :], w)
     return w
 
 
@@ -93,8 +94,6 @@ def _kernel(x_ref, *rest, bits: int, plane_widths: Sequence[int], bn: int,
             bk: int, k_out: int, n_levels: int, has_acc: bool, compute_dtype,
             x_mode: str, k_cols: int, has_scale: bool):
     nplanes = len(plane_widths)
-    if x_mode == "gathered":
-        xi_ref, rest = rest[0], rest[1:]
     plane_refs = rest[:nplanes]
     rest = rest[nplanes:]
     cb_ref, rest = rest[0], rest[1:]
@@ -115,22 +114,12 @@ def _kernel(x_ref, *rest, bits: int, plane_widths: Sequence[int], bn: int,
         o_ref[...] = acc_ref[...] if has_acc else jnp.zeros_like(o_ref)
 
     # --- fetch the (bm, bk) x tile (see module docstring: x_mode) -----------
-    if x_mode == "blocked":
-        xt = x_ref[...]
-    elif x_mode == "aligned":
-        xt = x_ref[...]
-        if k_cols % bk != 0:
-            # the group's padded K tail: weights there are zero, but the
-            # raw-x block read past `cols` is NaN-padded in interpret mode
-            fused = k_step * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (1, bk), 1)
-            xt = jnp.where(fused < k_cols, xt, jnp.zeros((), xt.dtype))
-    else:                              # "gathered": in-kernel take over the
-        xf = x_ref[...]                # VMEM-resident raw (bm, K) block
-        x_cols = xf.shape[1]
-        ii = xi_ref[0, :]              # (bk,) original col per fused K slot
-        xt = jnp.take(xf, jnp.minimum(ii, x_cols - 1), axis=1)
-        xt = jnp.where((ii < x_cols)[None, :], xt, jnp.zeros((), xt.dtype))
+    xt = x_ref[...]
+    if x_mode == "aligned" and k_cols % bk != 0:
+        # the group's padded K tail: weights there are zero, but the
+        # raw-x block read past `cols` is NaN-padded in interpret mode
+        fused = k_step * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        xt = jnp.where(fused < k_cols, xt, jnp.zeros((), xt.dtype))
 
     # --- unpack code planes -> (bn, bk) int32 codes -------------------------
     codes = None
@@ -140,7 +129,7 @@ def _kernel(x_ref, *rest, bits: int, plane_widths: Sequence[int], bn: int,
         codes = part if codes is None else codes | part
         shift += w
     # --- centroid lookup -----------------------------------------------------
-    wt = _dequant_tile(codes, cb_ref[...], n_levels, compute_dtype)
+    wt = _dequant_tile(codes, cb_ref[...], n_levels)
 
     # --- outlier-reservation epilogue ---------------------------------------
     if k_out > 0:
@@ -149,13 +138,18 @@ def _kernel(x_ref, *rest, bits: int, plane_widths: Sequence[int], bn: int,
         idx = idx_ref[...]            # (k_out, bk) global row ids, -1 invalid
         val = val_ref[...]            # (k_out, bk)
         for r in range(k_out):
-            hit = idx[r][None, :] == row_ids             # (bn, bk)
-            wt = jnp.where(hit, val[r][None, :].astype(compute_dtype), wt)
+            hit = idx[r:r + 1, :] == row_ids           # (bn, bk)
+            wt = jnp.where(hit, val[r:r + 1, :], wt)
 
     # --- MXU ------------------------------------------------------------------
+    # f32 compute asks for f32 products outright rather than leaving the
+    # pass count of an f32 matmul to Mosaic's default
     x = xt.astype(compute_dtype)
+    f32 = jnp.dtype(compute_dtype) == jnp.float32
     o_ref[...] += jax.lax.dot_general(
-        x, wt, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, wt.astype(compute_dtype), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
+        preferred_element_type=jnp.float32)
 
     if has_scale:
         # per-token (row) activation scale, folded in ONCE after the full
@@ -178,9 +172,9 @@ launch_count = 0
     static_argnames=("bits", "n", "bm", "bn", "bk", "interpret",
                      "compute_dtype", "x_mode", "x_base", "k_cols"),
 )
-def _dequant_matmul(x, planes, codebook, out_idx, out_val, acc, x_idx,
-                    x_scale, *, bits, n, bm, bn, bk, interpret,
-                    compute_dtype, x_mode, x_base, k_cols):
+def _dequant_matmul(x, planes, codebook, out_idx, out_val, acc, x_scale, *,
+                    bits, n, bm, bn, bk, interpret, compute_dtype, x_mode,
+                    x_base, k_cols):
     from repro.core import packing
 
     widths = packing.plane_widths(bits)
@@ -202,23 +196,14 @@ def _dequant_matmul(x, planes, codebook, out_idx, out_val, acc, x_idx,
         # block offset x_base — a plain shifted block fetch, no indexing
         in_specs = [pl.BlockSpec((bm, bk),
                                  lambda i, j, k, xb=x_base: (i, xb + k))]
-    elif x_mode == "gathered":
-        # raw x, whole K axis resident per M block (index map constant in
-        # j/k — Pallas keeps the block in VMEM across the (N, K) sweep)
-        in_specs = [pl.BlockSpec((bm, x.shape[1]), lambda i, j, k: (i, 0))]
     else:
         raise ValueError(f"unknown x_mode {x_mode!r}")
     operands = [x]
-    if x_mode == "gathered":
-        assert x_idx is not None and x_idx.shape == (k_padded // bk, bk), \
-            (None if x_idx is None else x_idx.shape, k_padded, bk)
-        in_specs.append(pl.BlockSpec((1, bk), lambda i, j, k: (k, 0)))
-        operands.append(x_idx)
     for w, p in zip(widths, planes):
         cpw = 32 // w
         in_specs.append(pl.BlockSpec((bn // cpw, bk), lambda i, j, k: (j, k)))
         operands.append(p)
-    in_specs.append(pl.BlockSpec((bk, n_levels), lambda i, j, k: (k, 0)))
+    in_specs.append(pl.BlockSpec((n_levels, bk), lambda i, j, k: (0, k)))
     operands.append(codebook)
     if k_out > 0:
         in_specs.append(pl.BlockSpec((k_out, bk), lambda i, j, k: (0, k)))
@@ -245,7 +230,7 @@ def _dequant_matmul(x, planes, codebook, out_idx, out_val, acc, x_idx,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -254,7 +239,7 @@ def _dequant_matmul(x, planes, codebook, out_idx, out_val, acc, x_idx,
 def dequant_matmul(
     x: Array,                     # (M, K) — fused-padded ("blocked") or raw
     planes: tuple,                # per-plane (n_words, K) uint32
-    codebook: Array,              # (K, 2**bits)
+    codebook: Array,              # (2**bits, K) f32
     out_idx: Optional[Array],     # (k_out, K) int32 global row ids, -1 pad
     out_val: Optional[Array],     # (k_out, K)
     *,
@@ -266,25 +251,31 @@ def dequant_matmul(
     interpret: bool = False,
     compute_dtype=jnp.float32,
     acc: Optional[Array] = None,  # (M, N) f32 running accumulator to fold in
-    x_mode: str = "blocked",      # "blocked" | "aligned" | "gathered"
+    x_mode: str = "blocked",      # "blocked" | "aligned"
     x_base: int = 0,              # aligned: x block offset (x_start // bk)
     k_cols: int = 0,              # aligned: unpadded fused K (tail mask)
-    x_idx: Optional[Array] = None,    # gathered: (K/bk, bk) int32 tables
     x_scale: Optional[Array] = None,  # (M, 1) f32 per-token act scale
 ) -> Array:
     """y = [acc +] x @ W^T for one uniform-bit-width CLAQ group.  Plane /
     codebook / outlier shapes must be padded to block multiples by the
-    caller (kernels/ops.py / kernels/plan.py do this); with the raw-x
-    modes ("aligned" / "gathered", module docstring) x itself needs only
-    its rows padded to bm.  `acc` seeds the output block at the first K
-    step, so multi-group (mixed-precision) matmuls accumulate inside the
-    kernel instead of through an XLA add per group; `x_scale` folds a
+    caller (kernels/ops.py / kernels/plan.py do this); in "aligned" mode
+    (module docstring) x itself needs only its rows padded to bm.  `acc`
+    seeds the output block at the first K step, so multi-group
+    (mixed-precision) matmuls accumulate inside the kernel instead of
+    through an XLA add per group; `x_scale` folds a
     per-token int8 activation scale into the output at the last K step
-    (pass it on the LAST launch of a multi-group chain only)."""
+    (pass it on the LAST launch of a multi-group chain only).
+
+    `interpret=True` runs the kernel through the Pallas interpreter, for
+    hosts without a TPU; on a TPU it raises, so the chip only ever runs
+    the compiled kernel."""
+    if interpret and _platform() == "tpu":
+        raise ValueError("on a TPU the kernel runs compiled; Pallas "
+                         "interpret mode is for hosts without one")
     global launch_count
     launch_count += 1
     return _dequant_matmul(x, tuple(planes), codebook, out_idx, out_val, acc,
-                           x_idx, x_scale,
+                           x_scale,
                            bits=bits, n=n, bm=bm, bn=bn, bk=bk,
                            interpret=interpret, compute_dtype=compute_dtype,
                            x_mode=x_mode, x_base=x_base, k_cols=k_cols)

@@ -1,13 +1,14 @@
 """Jit'd wrappers dispatching QuantizedTensor matmuls to the Pallas kernel
-(TPU / interpret) or the XLA reference path (CPU dry-run lowering).
+(compiled on TPU, interpreted elsewhere) or the XLA reference path.
 
 `qmatmul(x, qt)` computes x @ dequantize(qt)^T for the full multi-stripe,
 outlier-carrying format; the kernel path never materializes W in HBM.
+`kernel_mode` is the one place that decides, from the platform, which of
+the two runs and whether Pallas interprets the kernel.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +18,30 @@ from repro.core.quantized import QuantizedTensor
 
 from . import dequant_matmul as dm
 from . import ref as ref_lib
-from .plan import PreparedQuantizedTensor, validated_outliers
+from .plan import (PreparedQuantizedTensor, launch_bn, padded_rows,
+                   validated_outliers)
 
 Array = jax.Array
+
+
+def kernel_mode(mode: Optional[str] = None) -> Tuple[bool, bool]:
+    """(use_kernel, interpret) for a quantized matmul traced now.
+
+    On a TPU the answer is always the compiled Pallas kernel: asking for
+    the XLA reference (mode="ref") there raises, so a served program on
+    the chip can never fall back to it.  Elsewhere Pallas TPU kernels can
+    only run interpreted, and the XLA reference is the default
+    (mode=None); mode="kernel" selects the interpreted kernel.
+    """
+    if mode not in (None, "ref", "kernel"):
+        raise ValueError(f"unknown quantized-matmul mode {mode!r}")
+    if dm._platform() == "tpu":
+        if mode == "ref":
+            raise ValueError(
+                "on a TPU quantized matmuls run the compiled kernel; the "
+                "XLA reference is for hosts without one")
+        return True, False
+    return mode == "kernel", True
 
 
 def _pad_to(arr: Array, axis: int, mult: int, value=0) -> Array:
@@ -41,13 +63,13 @@ def stripe_matmul(
     *,
     bits: int,
     n: int,
-    interpret: bool = True,
     bm: int = dm.DEFAULT_BM,
     bn: int = dm.DEFAULT_BN,
     bk: int = dm.DEFAULT_BK,
     compute_dtype=jnp.float32,
 ) -> Array:
     """Single-stripe kernel call with all padding handled. x: (M, K)."""
+    interpret = kernel_mode("kernel")[1]
     m, k_dim = x.shape
     bm = min(bm, _round_up(m, 8))
     bn = min(bn, _round_up(n, 32))
@@ -55,7 +77,7 @@ def stripe_matmul(
 
     xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
     kp = xp.shape[1]
-    n_padded = _round_up(n, bn)
+    n_padded = padded_rows(n, bn, (bits,))
 
     planes = []
     for w, p in zip(packing.plane_widths(bits),
@@ -65,7 +87,7 @@ def stripe_matmul(
         p = p[: n_padded // cpw]
         planes.append(_pad_to(p, 1, bk))
 
-    cb = _pad_to(codebook.astype(jnp.float32), 0, bk)
+    cb = _pad_to(codebook.astype(jnp.float32).T, 1, bk)
     oi = ov = None
     if out_idx is not None and out_idx.shape[0] > 0:
         oi = _pad_to(out_idx.astype(jnp.int32), 1, bk, value=-1)
@@ -73,8 +95,8 @@ def stripe_matmul(
 
     y = dm.dequant_matmul(
         xp, tuple(planes), cb, oi, ov,
-        bits=bits, n=n_padded, bm=bm, bn=bn, bk=bk,
-        interpret=interpret, compute_dtype=compute_dtype)
+        bits=bits, n=n_padded, bm=bm, bn=launch_bn(bn, n_padded, bits),
+        bk=bk, interpret=interpret, compute_dtype=compute_dtype)
     return y[:m, :n]
 
 
@@ -112,33 +134,27 @@ def prepared_qmatmul(
     x: Array,
     pqt: PreparedQuantizedTensor,
     *,
-    interpret: bool = True,
     bm: int = dm.DEFAULT_BM,
     compute_dtype=jnp.float32,
-    gather: str = "kernel",
     act_dtype=None,
 ) -> Array:
     """Fused hot path: x (..., K) @ dequantize(pqt)^T -> (..., N).
 
     The plan did all per-tensor work offline, so this is exactly ONE
     `pallas_call` per distinct stripe bit-width, each accumulating into
-    the same output block via the kernel's acc operand.
-
-    gather="kernel" (default): the kernel consumes RAW x — aligned groups
-    read plain (i, k) blocks, permuted groups take their columns from a
-    VMEM-resident x block via the plan's per-bk-block index tables.  No
-    XLA gather, no padded activation copy (only rows pad to the M block).
-    Bit-identical to gather="xla", the pre-fold path kept for A/B
-    benchmarking: one XLA take of x into fused-padded order, then
-    "blocked" kernel launches.
+    the same output block via the kernel's acc operand.  Aligned groups
+    read plain (i, k) blocks of the RAW x — no gather, no padded
+    activation copy (only rows pad to the M block); a permuted group
+    takes its columns with one XLA gather of its slice of `gather_idx`
+    (Mosaic has no lane gather to do it in-kernel) and launches
+    "blocked".  Each launch tiles N by `plan.launch_bn`.
 
     act_dtype="int8": per-token dynamic absmax quantization of x; the
     kernel consumes int8 activations and the (m, 1) f32 scales fold into
-    the output block at the last K step of the LAST group's launch (the
-    XLA-gather path applies the same scales as one XLA multiply — the two
-    paths stay bit-identical).
+    the output block at the last K step of the LAST group's launch.
     """
     act_dtype = normalize_act_dtype(act_dtype)
+    interpret = kernel_mode("kernel")[1]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     scale = None
@@ -147,39 +163,26 @@ def prepared_qmatmul(
     m = x2.shape[0]
     bm = min(bm, _round_up(m, 8))
 
-    if gather == "xla":
-        xg = jnp.take(x2, pqt.gather_idx, axis=1, mode="fill", fill_value=0)
-        xp = _pad_to(xg, 0, bm)
-        y = None
-        off = 0
-        for g in pqt.groups:
-            xs = jax.lax.slice_in_dim(xp, off, off + g.k_padded, axis=1)
-            y = dm.dequant_matmul(
-                xs, g.planes, g.codebook, g.out_idx, g.out_val,
-                bits=g.bits, n=pqt.n_padded, bm=bm, bn=pqt.bn, bk=g.bk,
-                interpret=interpret, compute_dtype=compute_dtype, acc=y)
-            off += g.k_padded
-        y = y[:m, :pqt.rows]
-        if scale is not None:
-            y = y * scale
-    elif gather == "kernel":
-        xp = _pad_to(x2, 0, bm)
-        sp = _pad_to(scale, 0, bm) if scale is not None else None
-        y = None
-        for gi, g in enumerate(pqt.groups):
-            aligned = g.x_start is not None
-            y = dm.dequant_matmul(
-                xp, g.planes, g.codebook, g.out_idx, g.out_val,
-                bits=g.bits, n=pqt.n_padded, bm=bm, bn=pqt.bn, bk=g.bk,
-                interpret=interpret, compute_dtype=compute_dtype, acc=y,
-                x_mode="aligned" if aligned else "gathered",
-                x_base=g.x_start // g.bk if aligned else 0,
-                k_cols=g.k_cols, x_idx=g.x_idx,
-                x_scale=sp if gi == len(pqt.groups) - 1 else None)
-        y = y[:m, :pqt.rows]
-    else:
-        raise ValueError(f"unknown gather mode {gather!r} "
-                         "(expected 'kernel' or 'xla')")
+    xp = _pad_to(x2, 0, bm)
+    sp = _pad_to(scale, 0, bm) if scale is not None else None
+    y = None
+    off = 0
+    for gi, g in enumerate(pqt.groups):
+        if g.x_start is not None:
+            xg, fetch = xp, dict(x_mode="aligned", x_base=g.x_start // g.bk,
+                                 k_cols=g.k_cols)
+        else:
+            xg = jnp.take(xp, pqt.gather_idx[off:off + g.k_padded],
+                          axis=1, mode="fill", fill_value=0)
+            fetch = dict(x_mode="blocked")
+        y = dm.dequant_matmul(
+            xg, g.planes, g.codebook, g.out_idx, g.out_val,
+            bits=g.bits, n=pqt.n_padded, bm=bm,
+            bn=launch_bn(pqt.bn, pqt.n_padded, g.bits), bk=g.bk,
+            interpret=interpret, compute_dtype=compute_dtype, acc=y,
+            x_scale=sp if gi == len(pqt.groups) - 1 else None, **fetch)
+        off += g.k_padded
+    y = y[:m, :pqt.rows]
     return y.reshape(lead + (pqt.rows,)).astype(x.dtype)
 
 
@@ -203,8 +206,7 @@ def _prepared_ref_qmatmul(x: Array, pqt: PreparedQuantizedTensor,
     y = jnp.zeros(x.shape[:-1] + (rows,), jnp.float32)
     off = 0
     for g in pqt.groups:
-        Wg = jnp.take_along_axis(g.codebook.T.astype(jnp.float32),
-                                 g.unpack_codes(rows), axis=0)
+        Wg = jnp.take_along_axis(g.codebook, g.unpack_codes(rows), axis=0)
         Wg = ref_lib.ref_apply_outliers(Wg, g.out_idx, g.out_val)
         # XLA doesn't need the kernel's K padding — slice to the unpadded
         # group so total contraction is exactly `cols` (parity with the
@@ -223,10 +225,8 @@ def qmatmul(
     qt,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
     compute_dtype=None,
     act_dtype=None,
-    gather: str = "kernel",
 ) -> Array:
     """x (..., K) @ dequantize(qt)^T -> (..., N) for a QuantizedTensor or a
     PreparedQuantizedTensor.
@@ -234,12 +234,12 @@ def qmatmul(
     use_kernel=False: XLA reference path (gather-dequant + dot). This is what
     the CPU dry-run lowers (Pallas TPU kernels can't lower on the CPU
     backend); its HLO cost is the *baseline* the kernel improves on.
-    use_kernel=True: the Pallas kernel (interpret=True on CPU for tests).
-    Prepared tensors take the fused path: one launch per distinct bit-width,
-    with the stripe-permutation gather folded into the kernel (gather=
-    "kernel", default) or as the pre-fold XLA take (gather="xla" — the A/B
-    baseline, bit-identical).  act_dtype="int8" opts activations into
-    per-token dynamic int8 quantization (prepared tensors only).
+    use_kernel=True: the Pallas kernel (compiled on TPU, interpreted
+    elsewhere — `kernel_mode`).  Prepared tensors take the fused path, one
+    launch per distinct bit-width (`prepared_qmatmul`; under a device mesh
+    `dist.sharding.sharded_qmatmul` runs it per shard).  act_dtype="int8"
+    opts activations into per-token dynamic int8 quantization (prepared
+    tensors only).
     """
     if compute_dtype is None:
         compute_dtype = jnp.float32 if x.dtype == jnp.float32 else jnp.bfloat16
@@ -247,9 +247,8 @@ def qmatmul(
         if not use_kernel:
             return _prepared_ref_qmatmul(x, qt,
                                          act_dtype=act_dtype).astype(x.dtype)
-        return prepared_qmatmul(x, qt, interpret=interpret,
-                                compute_dtype=compute_dtype,
-                                gather=gather, act_dtype=act_dtype)
+        return prepared_qmatmul(x, qt, compute_dtype=compute_dtype,
+                                act_dtype=act_dtype)
     if normalize_act_dtype(act_dtype) is not None:
         raise ValueError(
             "act_dtype quantization needs an ahead-of-time plan — prepare "
@@ -274,7 +273,6 @@ def qmatmul(
             sov = jax.lax.slice_in_dim(ov, off, off + nc, axis=1)
         y = y + stripe_matmul(
             xs, s.packed, s.codebook, soi, sov,
-            bits=s.bits, n=qt.rows, interpret=interpret,
-            compute_dtype=compute_dtype)
+            bits=s.bits, n=qt.rows, compute_dtype=compute_dtype)
         off += nc
     return y.reshape(lead + (qt.rows,)).astype(x.dtype)

@@ -12,14 +12,13 @@ it once, at load/quantize time:
       carry zero codebooks and idx=-1 outliers, so they contribute exactly
       zero and never need masking at matmul time;
   (b) the per-stripe column slicing is folded into ONE gather index over
-      the activation's K axis, kept in two forms: `gather_idx` (flat, the
-      XLA `jnp.take(..., mode="fill")` path and the dequantize oracle) and
-      per-group `x_idx` per-bk-block tables the kernel consumes directly —
-      plus a static per-group alignment analysis: when a group's fused K
-      order is exactly original column order (single-bit-width tensors;
-      `build_quantized_tensor` emits an identity permutation), `x_start`
-      is set and the kernel fetches raw x blocks with NO indexing at all
-      (DESIGN.md §9);
+      the activation's K axis (`gather_idx`: the XLA
+      `jnp.take(..., mode="fill")` that puts a permuted group's columns in
+      fused order, and the dequantize oracle) — plus a static per-group
+      alignment analysis: when a group's fused K order is exactly original
+      column order (single-bit-width tensors; `build_quantized_tensor`
+      emits an identity permutation), `x_start` is set and the kernel
+      fetches raw x blocks with NO indexing at all (DESIGN.md §9);
   (c) outlier slots are pre-validated: the per-column count is converted
       to idx=-1 padding once, instead of a mask per matmul;
   (d) stripes are grouped by bit-width and concatenated along K, so a
@@ -36,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -54,15 +54,45 @@ def _round_up(v: int, mult: int) -> int:
     return ((v + mult - 1) // mult) * mult
 
 
+def _launch_unit(bits: int) -> int:
+    """Rows a multi-tile kernel launch must cover in multiples of: Mosaic
+    tiles a block's last two axes by (8, 128) unless the block spans the
+    whole axis.  The N tile is the output block's lane extent (a multiple
+    of 128), and a code plane of width w packs 32 // w rows per word, so
+    its block has tile // (32 // w) word rows (a multiple of 8): 256 rows
+    where a width-1 plane exists, else 128."""
+    return max(128, 256 // min(packing.plane_widths(bits)))
+
+
+def padded_rows(rows: int, bn: int, bit_widths) -> int:
+    """`rows` padded to whole `bn` tiles and, where one launch tile would
+    not span them all, to whole launch units — so `launch_bn` always finds
+    a legal tile without launching over every row of a large matrix."""
+    unit = max(_launch_unit(b) for b in bit_widths)
+    return _round_up(rows, bn if rows <= unit else math.lcm(bn, unit))
+
+
+def launch_bn(bn: int, n_padded: int, bits: int) -> int:
+    """N tile of one kernel launch over `n_padded` rows stored in `bn`-row
+    tiles: the fewest whole tiles whose blocks Mosaic accepts
+    (`_launch_unit`), or one tile over every row, which is always legal.
+    The plan's `bn` stays the unit of padding and of sharding; only the
+    launch grid coarsens."""
+    unit = _launch_unit(bits)
+    tiles = n_padded // bn
+    for d in range(1, tiles):
+        if tiles % d == 0 and (bn * d) % unit == 0:
+            return bn * d
+    return n_padded
+
+
 @dataclasses.dataclass(frozen=True)
 class PlanGroup:
     """All same-bit-width stripes, concatenated along K and block-padded."""
     planes: Tuple[Array, ...]   # per plane-width: (n_padded//cpw, k_padded) u32
-    codebook: Array             # (k_padded, 2**bits) f32, zero at padding
+    codebook: Array             # (2**bits, k_padded) f32, zero at padding
     out_idx: Optional[Array]    # (k_out, k_padded) int32, -1 = no outlier
     out_val: Optional[Array]    # (k_out, k_padded) f32
-    x_idx: Optional[Array]      # (k_padded//bk, bk) int32 per-block x column
-    #                             tables (None when x_start is set)
     bits: int                   # static
     bk: int                     # static — K block size for this group
     k_cols: int                 # static — unpadded fused K of the group
@@ -73,7 +103,7 @@ class PlanGroup:
 
     @property
     def k_padded(self) -> int:
-        return self.codebook.shape[0]
+        return self.codebook.shape[-1]
 
     def unpack_codes(self, rows: int) -> Array:
         """Recombine the group's split planes -> (rows, k_padded) int32."""
@@ -88,7 +118,7 @@ class PlanGroup:
 
 jax.tree_util.register_dataclass(
     PlanGroup,
-    data_fields=["planes", "codebook", "out_idx", "out_val", "x_idx"],
+    data_fields=["planes", "codebook", "out_idx", "out_val"],
     meta_fields=["bits", "bk", "k_cols", "x_start"])
 
 
@@ -120,7 +150,7 @@ class PreparedQuantizedTensor:
     def x_gather_free(self) -> bool:
         """True iff every group fetches raw x blocks without per-column
         indexing (all groups aligned) — the fused matmul then contains no
-        gather of any kind, in-kernel or XLA."""
+        gather of the activations."""
         return all(g.x_start is not None for g in self.groups)
 
     def shards_whole_tiles(self, parts: int) -> bool:
@@ -131,11 +161,18 @@ class PreparedQuantizedTensor:
         bn is a multiple of the full 32-row packing word — plans built
         with a smaller or unaligned bn cap replicate (a width-1 plane
         packs 32 rows per word, so e.g. bn=16 tile boundaries fall
-        mid-word).  dist/sharding.spec_for_quantized uses this as the
-        divisibility guard: shard the unit along N only when every shard
-        keeps whole word-aligned tiles, otherwise replicate the whole
-        unit — never tear it."""
-        return parts > 1 and self.bn % 32 == 0 and self.n_tiles % parts == 0
+        mid-word).  Each shard's rows must also launch as a legal kernel
+        tile that is not all of a large shard: within one launch unit, or
+        whole units (`launch_bn`).  dist/sharding.spec_for_quantized uses
+        this as the divisibility guard: shard the unit along N only when
+        every shard keeps whole word-aligned tiles, otherwise replicate
+        the whole unit — never tear it."""
+        if not (parts > 1 and self.bn % 32 == 0
+                and self.n_tiles % parts == 0):
+            return False
+        local = self.n_padded // parts
+        return all(local <= unit or local % unit == 0
+                   for unit in (_launch_unit(g.bits) for g in self.groups))
 
     def dequantize(self, dtype=jnp.float32) -> Array:
         """Reference dequantization from the *prepared* layout (oracle for
@@ -144,8 +181,7 @@ class PreparedQuantizedTensor:
         W = jnp.zeros((rows, cols + 1), jnp.float32)   # last col: padding sink
         off = 0
         for g in self.groups:
-            Wg = jnp.take_along_axis(g.codebook.T.astype(jnp.float32),
-                                     g.unpack_codes(rows), axis=0)
+            Wg = jnp.take_along_axis(g.codebook, g.unpack_codes(rows), axis=0)
             if g.out_idx is not None:
                 safe = jnp.where(g.out_idx >= 0, g.out_idx, rows)
                 colk = jnp.broadcast_to(
@@ -166,7 +202,7 @@ class PreparedQuantizedTensor:
             if g.out_idx is not None:
                 total += 32.0 * float(jnp.sum(g.out_idx[:, :g.k_cols] >= 0))
             if include_codebooks:
-                total += g.k_cols * g.codebook.shape[1] * 16.0
+                total += g.k_cols * 2 ** g.bits * 16.0
         return total / (rows * cols)
 
 
@@ -214,13 +250,14 @@ def _static_group_layout(stripes, bk: int):
 
 
 def _aligned_x_starts(qt: QuantizedTensor, layout):
-    """Per-group x_start, or None where the group needs per-column index
-    tables.  A group is *aligned* when its fused K order is exactly the
-    original columns [s0, s0 + k_cols) with s0 a bk multiple — true for
+    """Per-group x_start, or None where the group's activations must be
+    gathered into fused order.  A group is *aligned* when its fused K
+    order is exactly the original columns [s0, s0 + k_cols) with s0 a bk
+    multiple — true for
     every single-bit-width tensor (`build_quantized_tensor` sorts columns
     ascending within each bit-class, so one class == identity).  Decided
     from concrete col_perm values at plan time; under tracing (prepare
-    inside jit) it conservatively falls back to index tables.  For layer
+    inside jit) it conservatively falls back to the gather.  For layer
     stacks the whole stack must agree (the flag is static, shared by every
     member)."""
     try:
@@ -228,7 +265,7 @@ def _aligned_x_starts(qt: QuantizedTensor, layout):
     except (jax.errors.TracerArrayConversionError,
             jax.errors.ConcretizationTypeError):
         # traced col_perm (prepare under jit/vmap): no static analysis —
-        # every group conservatively takes the index-table path.  Anything
+        # every group conservatively takes the gathered path.  Anything
         # else np.asarray raises is a real defect and must propagate.
         return [None] * len(layout)
     flat = perm.reshape(-1, perm.shape[-1])
@@ -279,7 +316,7 @@ def _build_plan(qt: QuantizedTensor, *, bn: int, layout,
                 x_starts) -> PreparedQuantizedTensor:
     rows = qt.rows
     bn = min(bn, _round_up(rows, 32))
-    n_padded = _round_up(rows, bn)
+    n_padded = padded_rows(rows, bn, [bits for bits, *_ in layout])
 
     oi, ov = validated_outliers(qt)
 
@@ -302,7 +339,7 @@ def _build_plan(qt: QuantizedTensor, *, bn: int, layout,
         cb = jnp.concatenate(
             [qt.stripes[si].codebook for _, si in members], axis=0) \
             if len(members) > 1 else qt.stripes[members[0][1]].codebook
-        cb = jnp.pad(cb.astype(jnp.float32), ((0, k_padded - k_cols), (0, 0)))
+        cb = jnp.pad(cb.astype(jnp.float32).T, ((0, 0), (0, k_padded - k_cols)))
 
         g_oi = g_ov = None
         if oi is not None:
@@ -330,8 +367,6 @@ def _build_plan(qt: QuantizedTensor, *, bn: int, layout,
 
         groups.append(PlanGroup(
             planes=tuple(planes), codebook=cb, out_idx=g_oi, out_val=g_ov,
-            x_idx=(None if x_start is not None
-                   else idx.reshape(k_padded // g_bk, g_bk)),
             bits=bits, bk=g_bk, k_cols=k_cols, x_start=x_start))
 
     return PreparedQuantizedTensor(

@@ -43,6 +43,8 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.core import APConfig, CLAQConfig, ORConfig
 from repro.data import calibration_set
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.quantize import claq_quantize, claq_quantize_with_draft
 from repro.models import api
 from repro.serve import (AdmissionController, AdmissionRejected,
@@ -72,7 +74,7 @@ def _build_mesh(args):
             f"mesh {dp}x{tp} needs {dp * tp} devices, have {n_dev} "
             f"(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
             f"to emulate on one host)")
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return make_mesh((dp, tp), ("data", "model"))
 
 
 def main(argv=None):
@@ -207,6 +209,7 @@ def main(argv=None):
                     help="print the uniform metrics report at exit (every "
                          "stats() counter through the metrics registry)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = api.init_params(jax.random.PRNGKey(0), cfg)

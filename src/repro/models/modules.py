@@ -9,7 +9,7 @@ concerns are threaded through module-level context:
   * **Taps**: during (eager) calibration runs, dense() streams its input
     activations into per-name Hessian accumulators (H += 2 x^T x) — the JAX
     answer to torch forward hooks, memory-light because only the (in,in)
-    moment matrix is kept.
+    moment matrix is kept, and kept in host memory.
   * **Quantized dispatch**: a params leaf may be a QuantizedTensor instead of
     a dense kernel; dense() then routes through kernels.ops.qmatmul.
 """
@@ -21,6 +21,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import gptq
 from repro.core.quantized import QuantizedTensor
@@ -60,20 +61,37 @@ def scoped_name(name: str) -> str:
 # ---------------------------------------------------------------------------
 
 class TapCollector:
-    """Streams dense() inputs into per-matrix Hessian accumulators."""
+    """Streams dense() inputs into per-matrix Hessian sums held in HOST
+    memory.  Each record computes 2 x^T x on the device and adds it into
+    a numpy sum, so the device holds one Hessian at a time: a full-width
+    model's sums outgrow the chip (qwen2_1p5b's 28 down-projection inputs
+    alone are 28 x 8960^2 f32 = 9 GB).  The sums equal a device
+    accumulator's up to the order of f32 rounding."""
 
     def __init__(self):
         self.hessians: Dict[str, gptq.HessianState] = {}
 
     def record(self, name: str, x: Array):
-        in_dim = x.shape[-1]
+        part = gptq.accumulate_hessian(gptq.init_hessian(x.shape[-1]), x)
+        H = np.array(part.H)
         st = self.hessians.get(name)
         if st is None:
-            st = gptq.init_hessian(in_dim)
-        self.hessians[name] = gptq.accumulate_hessian(st, x)
+            self.hessians[name] = gptq.HessianState(H, float(part.count))
+        else:
+            np.add(st.H, H, out=st.H)
+            self.hessians[name] = st._replace(
+                count=st.count + float(part.count))
 
-    def finalized(self) -> Dict[str, Array]:
-        return {k: gptq.finalize_hessian(v) for k, v in self.hessians.items()}
+    def finalized(self) -> Dict[str, np.ndarray]:
+        """{name: (in, in) f32 host Hessian}, finalized in place — the
+        collector empties as it goes, so host memory never holds two
+        copies."""
+        out = {}
+        for name in list(self.hessians):
+            st = self.hessians.pop(name)
+            out[name] = np.divide(st.H, np.float32(max(st.count, 1.0)),
+                                  out=st.H)
+        return out
 
 
 @contextlib.contextmanager
@@ -110,33 +128,31 @@ def record_expert_inputs(name: str, x_e: Array):
 # ---------------------------------------------------------------------------
 
 class QuantMode:
-    """'ref' = XLA dequant+dot (CPU dry-run path); 'kernel' = Pallas kernel
-    (interpret=True off-TPU).  `act_dtype` opts quantized matmuls into
-    per-token int8 activation quantization ("int8"; None/"f32" = full
-    precision) — an engine-level deployment knob (DESIGN.md §9), read at
-    trace time like `mode`."""
-    mode: str = "ref"
-    interpret: bool = True
+    """What runs quantized matmuls, read at trace time inside dense() and
+    resolved by `kernels.ops.kernel_mode`: on a TPU always the compiled
+    Pallas kernel; elsewhere `mode` None/'ref' = XLA dequant+dot and
+    'kernel' = the Pallas kernel in interpret mode.  `act_dtype` opts
+    quantized matmuls into per-token int8 activation quantization ("int8";
+    None/"f32" = full precision) — an engine-level deployment knob
+    (DESIGN.md §9)."""
+    mode: Optional[str] = None
     act_dtype: Optional[str] = None
 
 
 @contextlib.contextmanager
-def quant_mode(mode: str, interpret: bool = True,
-               act_dtype: Optional[str] = None):
-    prev = (QuantMode.mode, QuantMode.interpret, QuantMode.act_dtype)
-    QuantMode.mode, QuantMode.interpret = mode, interpret
-    QuantMode.act_dtype = act_dtype
+def quant_mode(mode: Optional[str], act_dtype: Optional[str] = None):
+    prev = (QuantMode.mode, QuantMode.act_dtype)
+    QuantMode.mode, QuantMode.act_dtype = mode, act_dtype
     try:
         yield
     finally:
-        QuantMode.mode, QuantMode.interpret, QuantMode.act_dtype = prev
+        QuantMode.mode, QuantMode.act_dtype = prev
 
 
 @contextlib.contextmanager
 def activation_quant(act_dtype: Optional[str]):
     """Scope ONLY the activation quantization mode (the ServingEngine wraps
-    its jitted steps with this so `mode`/`interpret` stay whatever the
-    caller set)."""
+    its jitted steps with this so `mode` stays whatever the caller set)."""
     prev = QuantMode.act_dtype
     QuantMode.act_dtype = act_dtype
     try:
@@ -152,15 +168,23 @@ def activation_quant(act_dtype: Optional[str]):
 def dense(p: Dict[str, Any], x: Array, name: str = "dense") -> Array:
     """y = x @ kernel (+ bias). kernel: (in, out) array, or a
     QuantizedTensor / PreparedQuantizedTensor in paper layout (out, in).
-    Prepared leaves take the fused one-launch-per-bit-width kernel path."""
+    Prepared leaves take the fused one-launch-per-bit-width kernel path,
+    per shard when a device mesh is active."""
     full = scoped_name(name)
     kernel = p["kernel"]
     if isinstance(kernel, (QuantizedTensor, PreparedQuantizedTensor)):
+        from repro.dist import context as dctx
+        from repro.dist import sharding as dshard
         from repro.kernels import ops as kops
-        y = kops.qmatmul(x, kernel,
-                         use_kernel=(QuantMode.mode == "kernel"),
-                         interpret=QuantMode.interpret,
-                         act_dtype=QuantMode.act_dtype)
+        use_kernel = kops.kernel_mode(QuantMode.mode)[0]
+        mesh = dctx.get_mesh() if use_kernel else None
+        if (mesh is not None and mesh.size > 1
+                and isinstance(kernel, PreparedQuantizedTensor)):
+            y = dshard.sharded_qmatmul(x, kernel, mesh,
+                                       act_dtype=QuantMode.act_dtype)
+        else:
+            y = kops.qmatmul(x, kernel, use_kernel=use_kernel,
+                             act_dtype=QuantMode.act_dtype)
     else:
         _maybe_record(full, x)
         y = x @ kernel.astype(x.dtype)

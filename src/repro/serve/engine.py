@@ -34,8 +34,8 @@ quantized serving, DESIGN.md §9) — opt-in because it changes numerics
 (bounded by scale/2 * ||W||_1 per output element), unlike every other
 engine knob, which is bit-exact.
 
-Multi-device serving: pass ``mesh=`` (e.g. ``jax.make_mesh((2, 4),
-("data", "model"))``) and the engine device_puts the prepared params with
+Multi-device serving: pass ``mesh=`` (e.g. ``launch.mesh.make_mesh((2,
+4), ("data", "model"))``) and the engine device_puts the prepared params with
 ``dist.sharding`` rules — PreparedQuantizedTensor units split along N over
 "model" with whole (bn, bk) tiles per shard, dense leaves by the generic
 TP rule — shards the slot cache over "dp" (plus KV heads over "model"),
@@ -149,6 +149,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 import numpy as np
 
 from repro.analysis.trace_rules import TraceSentinel
@@ -574,9 +575,9 @@ class ServingEngine:
         self.admission_rejections = 0
 
         # act_dtype scopes the per-token int8 activation quantization of
-        # every quantized matmul inside the jitted steps; QuantMode.mode /
-        # .interpret stay whatever the ambient context set (the wrap runs
-        # at trace time — QuantMode is read inside dense()).
+        # every quantized matmul inside the jitted steps; QuantMode.mode
+        # stays whatever the ambient context set (the wrap runs at trace
+        # time — QuantMode is read inside dense()).
         #
         # The jitted steps return (logits, cache, nonfinite) — the third
         # output is the per-row non-finite count when guards are on, None
@@ -1958,7 +1959,12 @@ class ServingEngine:
         # the pending last_token (whose K/V the window itself writes)
         base_fill = {uid: self._fill(r) for uid, r in self.active.items()}
 
+        # the chain feeds each draft decode's argmax back in, and on a
+        # mesh that output is typed as replicated over it: start from the
+        # same placement, or the second call retraces
         cur = jnp.asarray(self.last_token, jnp.int32)
+        if self.mesh is not None:
+            cur = jax.device_put(cur, NamedSharding(self.mesh, P()))
         d_cols = []                                     # device-resident
         iv = self._inject_vec()
         with self._mesh_scope():

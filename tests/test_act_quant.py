@@ -64,8 +64,9 @@ def test_act_dtype_rejected_without_plan():
 ])
 def test_int8_error_bound_all_paths(spec, k_out):
     """The int8 path's deviation from the f32 reference stays under the
-    analytic bound scale/2 * ||W||_1 on every dispatch: in-kernel gather,
-    XLA gather (bitwise-identical pair), and the XLA ref path."""
+    analytic bound scale/2 * ||W||_1 on every dispatch: raw x blocks,
+    x through the XLA gather (bitwise-identical pair), and the XLA ref
+    path."""
     rng = np.random.default_rng(sum(b for b, _ in spec) + k_out)
     qt = _make_qt(rng, rows=64, stripe_spec=spec, k_out=k_out)
     pqt = prepare_for_inference(qt)
@@ -75,10 +76,12 @@ def test_int8_error_bound_all_paths(spec, k_out):
     bound = bound * 1.01 + 1e-5       # epsilon for f32 accumulation order
 
     y_ker = ops.prepared_qmatmul(x, pqt, act_dtype="int8")
-    y_pre = ops.prepared_qmatmul(x, pqt, gather="xla", act_dtype="int8")
+    y_pre = ops.prepared_qmatmul(x, dataclasses.replace(pqt, groups=tuple(
+        dataclasses.replace(g, x_start=None) for g in pqt.groups)),
+        act_dtype="int8")
     y_xla = ops.qmatmul(x, pqt, use_kernel=False, act_dtype="int8")
     assert np.array_equal(np.asarray(y_ker), np.asarray(y_pre)), \
-        "int8 gather modes must match bitwise (same values, same order)"
+        "int8 x fetches must match bitwise (same values, same order)"
     for y in (y_ker, y_xla):
         err = np.abs(np.asarray(y - y_ref))
         assert (err <= bound).all(), (err.max(), bound.max())

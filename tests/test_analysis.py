@@ -384,7 +384,7 @@ def test_verify_contracts_raises_on_violation(small_models, monkeypatch):
     cfg, params, _ = small_models
     monkeypatch.setattr(
         afx, "lowered_decode_text",
-        lambda engine, interpret=True: _mod(
+        lambda engine: _mod(
             "  %o = token[] outfeed(%w, token[] %tok)"))
     with pytest.raises(ContractViolation, match="HLO-HT1"):
         ServingEngine(params, cfg, n_slots=2, max_len=32, prepare=False,

@@ -119,6 +119,7 @@ ENTRY main (x.0: f32[8,4]) -> f32[8,4] {
 
 def test_rowsharded_quantizer_matches_single_device(subproc):
     subproc("""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import CLAQConfig, quantize_matrix
 rng = np.random.default_rng(0)
@@ -127,7 +128,7 @@ X = rng.normal(size=(256, 64)).astype(np.float32)
 H = jnp.asarray(2 * X.T @ X)
 cfg = CLAQConfig(bits=3, method="kmeans", kmeans_iters=5, gptq_blocksize=32)
 qt1, Q1, st1 = quantize_matrix(W, H, cfg)
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 qt8, Q8, st8 = quantize_matrix(W, H, cfg, mesh=mesh, shard_axis="model")
 np.testing.assert_allclose(np.asarray(Q1), np.asarray(Q8), rtol=1e-4, atol=1e-5)
 assert abs(st1.proxy_loss - st8.proxy_loss) / max(st1.proxy_loss, 1e-9) < 1e-3
@@ -137,11 +138,11 @@ print("rowsharded parity OK")
 
 def test_compressed_psum_error_feedback(subproc):
     subproc("""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.dist.compat import shard_map
 from repro.optim import compressed_psum
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 gs = jnp.asarray(rng.normal(size=(8, 32)).astype(np.float32))
 err0 = jnp.zeros((8, 32), jnp.float32)
@@ -150,7 +151,7 @@ def body(g, e):
     out, new_e = compressed_psum({"g": g}, {"g": e}, "data")
     return out["g"], new_e["g"]
 
-fn = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
                out_specs=(P("data"), P("data")), check_vma=False)
 out, err = fn(gs, err0)
 true_mean = np.asarray(gs).mean(axis=0)
@@ -170,6 +171,7 @@ def test_small_mesh_dryrun_lower_compile(subproc):
     """The dry-run path end-to-end on a 2x4 debug mesh with a smoke config:
     proves the sharding rules + constraints lower on multi-device."""
     subproc("""
+from repro.launch.mesh import make_mesh
 import dataclasses, jax, jax.numpy as jnp
 from repro.configs import get_smoke_config, SHAPES_BY_NAME
 from repro.dist import sharding as shd, context as dctx
@@ -177,7 +179,7 @@ from repro.models import api
 from repro.optim import OptimConfig, OptState, init_opt_state
 from repro.train import make_train_step
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = dataclasses.replace(get_smoke_config("llama1_7b"),
                           d_model=64, n_heads=4, n_kv_heads=4, head_dim=16,
                           d_ff=128, vocab=256)
@@ -205,6 +207,7 @@ def test_multi_device_train_step_runs(subproc):
     """Actually EXECUTE a sharded train step on 8 devices (not just lower)
     and check the loss matches the single-device value."""
     subproc("""
+from repro.launch.mesh import make_mesh
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.dist import sharding as shd, context as dctx
@@ -225,7 +228,7 @@ batch = {"tokens": data.batch_at(0)}
 step = jax.jit(make_train_step(cfg, ocfg))
 _, _, m_single = step(params, opt, batch)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 pshard = shd.tree_shardings(params, shd.spec_for_param, cfg, mesh)
 params_d = jax.device_put(params, pshard)
 opt_d = init_opt_state(params_d, ocfg)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.serve import ServingEngine
 from repro.models import api
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -28,7 +29,7 @@ def test_trivial_mesh_engine_matches_no_mesh():
     cfg = dataclasses.replace(get_smoke_config("llama1_7b"), vocab=64,
                               n_layers=1)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng0 = ServingEngine(params, cfg, n_slots=2, max_len=32, min_bucket=8)
     engm = ServingEngine(params, cfg, n_slots=2, max_len=32, min_bucket=8,
                          mesh=mesh)
@@ -56,7 +57,7 @@ def test_mesh_preempt_resume_parity_and_cache_pinning():
     fin0 = eng0.take_finished()
     base = [fin0[u].tokens for u in uids0]
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng = ServingEngine(params, cfg, n_slots=2, max_len=32, min_bucket=8,
                         mesh=mesh)
     uids = eng.add_requests(prompts, max_new_tokens=8)
@@ -91,6 +92,7 @@ def test_sharded_speculative_token_parity(subproc):
     counters stay constant (one draft decode, one verify) and the draft's
     prepared plans shard over "model" like the target's."""
     subproc("""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
@@ -126,7 +128,7 @@ eng0 = ServingEngine(qparams, cfg, n_slots=4, max_len=64, min_bucket=8,
                      plan_bn=32)
 t0 = serve(eng0, wave1) + serve(eng0, wave2)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for gamma in (2, 4):
     eng = ServingEngine(qparams, cfg, n_slots=4, max_len=64, min_bucket=8,
                         plan_bn=32, mesh=mesh, draft_params=dparams,
@@ -162,6 +164,7 @@ def test_mesh_paged_decode_parity(subproc):
     host-side page release, and the batch-1 replay reinsert all run with
     sharded pool leaves."""
     subproc("""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import jax
 from repro.configs import get_smoke_config
@@ -190,7 +193,7 @@ t0 = serve(eng0, wave1) + serve(eng0, wave2)
 # kv_pages=31 -> pool leaves carry 32 page rows (31 + scratch), which the
 # data=2 axis splits evenly; default capacity (4*64/8=32 pages -> 33 rows)
 # would not.
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 paged_kw = dict(kv_layout="paged", page_size=8, kv_pages=31)
 eng = ServingEngine(params, cfg, n_slots=4, max_len=64, min_bucket=8,
                     prepare=False, mesh=mesh, **paged_kw)
@@ -230,6 +233,7 @@ print("mesh paged parity OK: tokens bitwise, pages sharded over data,"
 
 def test_sharded_engine_token_parity_and_weight_residency(subproc):
     subproc("""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
@@ -269,7 +273,7 @@ eng1 = ServingEngine(qparams, cfg, n_slots=4, max_len=64, min_bucket=8,
                      plan_bn=32)
 t1 = serve(eng1, wave1) + serve(eng1, wave2)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 eng2 = ServingEngine(qparams, cfg, n_slots=4, max_len=64, min_bucket=8,
                      plan_bn=32, mesh=mesh)
 t2 = serve(eng2, wave1) + serve(eng2, wave2)
@@ -313,3 +317,52 @@ assert rep.rules_run == ["HLO-AG1", "HLO-CB1"] and not rep.findings, (
 print("dist serving parity OK: decode clean under HLO-AG1/HLO-CB1,"
       " weight-shard threshold", threshold, "B")
 """, devices=8, timeout=900)
+
+
+def test_sharded_kernel_matches_single_device_bitwise(subproc):
+    """The kernel's mesh path (dist.sharding.sharded_qmatmul: per-shard
+    Pallas launches under shard_map, outlier row ids shifted per shard)
+    is BITWISE the single-device fused kernel, interpreted, on a 2x2
+    (data x model) mesh — for an AP+OR tensor whose outliers fall on both
+    model shards, with f32 and int8 activations, 2-D and 3-D x."""
+    subproc("""
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import APConfig, CLAQConfig, ORConfig, quantize_matrix
+from repro.dist.sharding import sharded_qmatmul
+from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+from repro.models import modules as nn
+
+rng = np.random.default_rng(0)
+rows, cols = 128, 160
+W = rng.normal(size=(rows, cols)).astype(np.float32)
+W[:, :12] += rng.standard_t(df=2, size=(rows, 12)) * 4
+X = rng.normal(size=(256, cols)).astype(np.float32)
+qt, _, _ = quantize_matrix(jnp.asarray(W), jnp.asarray(2 * X.T @ X),
+                           CLAQConfig(bits=2, method="kmeans",
+                                      kmeans_iters=4, gptq_blocksize=32,
+                                      ap=APConfig(2.5, 2, 4),
+                                      orr=ORConfig(0.15)))
+pqt = qt.prepare(bn=32)              # 4 tiles of 32 rows: 2 per model shard
+assert len(pqt.groups) == 2 and not pqt.x_gather_free
+ids = np.concatenate([np.asarray(g.out_idx).ravel() for g in pqt.groups
+                      if g.out_idx is not None])
+ids = ids[ids >= 0]
+assert (ids < rows // 2).any() and (ids >= rows // 2).any(), "one shard"
+
+mesh = make_mesh((2, 2), ("data", "model"))
+assert pqt.shards_whole_tiles(2)
+with nn.quant_mode("kernel"):
+    for shape in ((4, cols), (2, 3, cols)):
+        x = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+        for act in (None, "int8"):
+            one = jax.jit(lambda a, p: ops.prepared_qmatmul(
+                a, p, act_dtype=act))(x, pqt)
+            with mesh:
+                got = jax.jit(lambda a, p: sharded_qmatmul(
+                    a, p, mesh, act_dtype=act))(x, pqt)
+            assert got.shape == one.shape, (got.shape, one.shape)
+            assert np.array_equal(np.asarray(got), np.asarray(one)), (
+                shape, act, float(jnp.max(jnp.abs(got - one))))
+print("sharded kernel bitwise OK")
+""", devices=4, timeout=600)

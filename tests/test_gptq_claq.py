@@ -133,6 +133,25 @@ def test_hessian_accumulation():
     np.testing.assert_allclose(np.asarray(H), expected, rtol=1e-6)
 
 
+def test_tap_collector_host_sums_match_device_accumulator():
+    """Calibration keeps its Hessian sums on the host; they must equal the
+    device accumulator's to f32 rounding, and finalizing empties the
+    collector (host memory never holds the sums twice)."""
+    from repro.models.modules import TapCollector
+    rng = np.random.default_rng(5)
+    xs = [jnp.asarray(rng.normal(size=(3, 7, 16)), jnp.float32)
+          for _ in range(3)]
+    col = TapCollector()
+    st = gptq.init_hessian(16)
+    for x in xs:
+        col.record("m", x)
+        st = gptq.accumulate_hessian(st, x)
+    got = col.finalized()
+    assert set(got) == {"m"} and col.hessians == {}
+    np.testing.assert_allclose(got["m"], np.asarray(gptq.finalize_hessian(st)),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_prepare_hinv_cholesky_is_upper_factor():
     rng = np.random.default_rng(3)
     X = rng.normal(size=(64, 16)).astype(np.float32)

@@ -7,6 +7,7 @@ import pytest
 
 from repro.core import APConfig, CLAQConfig, ORConfig, quantize_matrix
 from repro.core import packing
+from repro.kernels import dequant_matmul as dm
 from repro.kernels import ops, ref as ref_lib
 
 jax.config.update("jax_platform_name", "cpu")
@@ -36,8 +37,7 @@ def test_stripe_matmul_matches_oracle(bits, m, n, k_dim):
     x = jnp.asarray(rng.normal(size=(m, k_dim)).astype(np.float32))
     y_ref = ref_lib.ref_dequant_matmul(x, packed, cb, None, None,
                                        bits=bits, n=n)
-    y = ops.stripe_matmul(x, packed, cb, None, None, bits=bits, n=n,
-                          interpret=True)
+    y = ops.stripe_matmul(x, packed, cb, None, None, bits=bits, n=n)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-3)
 
@@ -49,7 +49,7 @@ def test_outlier_epilogue(k_out):
     packed, cb, oi, ov = _make_stripe(rng, n, k_dim, 2, k_out=k_out)
     x = jnp.asarray(rng.normal(size=(5, k_dim)).astype(np.float32))
     y_ref = ref_lib.ref_dequant_matmul(x, packed, cb, oi, ov, bits=2, n=n)
-    y = ops.stripe_matmul(x, packed, cb, oi, ov, bits=2, n=n, interpret=True)
+    y = ops.stripe_matmul(x, packed, cb, oi, ov, bits=2, n=n)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-3)
 
@@ -63,7 +63,7 @@ def test_dtype_sweep(dtype):
     y_ref = ref_lib.ref_dequant_matmul(x.astype(jnp.float32), packed, cb,
                                        None, None, bits=4, n=n)
     y = ops.stripe_matmul(x.astype(jnp.float32), packed, cb, None, None,
-                          bits=4, n=n, interpret=True,
+                          bits=4, n=n,
                           compute_dtype=jnp.float32 if dtype == jnp.float32
                           else jnp.bfloat16)
     tol = 1e-3 if dtype == jnp.float32 else 0.15
@@ -85,13 +85,81 @@ def test_full_quantized_tensor_qmatmul():
         ap=APConfig(2.5, 2, 4), orr=ORConfig(0.15)))
     x = jnp.asarray(rng.normal(size=(7, cols)).astype(np.float32))
     y_ref = ref_lib.ref_qmatmul(x, qt)
-    y_ker = ops.qmatmul(x, qt, use_kernel=True, interpret=True)
+    y_ker = ops.qmatmul(x, qt, use_kernel=True)
     np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-3)
     # XLA ref path agrees too
     y_xla = ops.qmatmul(x, qt, use_kernel=False)
     np.testing.assert_allclose(np.asarray(y_xla), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def _small_prepared(rng, rows=64, cols=128):
+    from repro.core.quantized import build_quantized_tensor
+    codes = rng.integers(0, 4, size=(rows, cols))
+    cbs = np.sort(rng.normal(size=(cols, 4)), axis=1).astype(np.float32)
+    W = jnp.asarray(rng.normal(size=(rows, cols)), jnp.float32)
+    return build_quantized_tensor(
+        jnp.asarray(codes, jnp.int32), jnp.asarray(cbs), np.full(cols, 2),
+        np.zeros(cols, np.int32), W, jnp.zeros((rows, cols), bool)).prepare()
+
+
+def _record_kernel_calls(monkeypatch):
+    """Replace the kernel entry with a recorder of its keywords."""
+    calls = []
+
+    def fake(x, planes, codebook, out_idx, out_val, *, n, **kw):
+        calls.append(kw)
+        return jnp.zeros((x.shape[0], n), jnp.float32)
+
+    monkeypatch.setattr(dm, "dequant_matmul", fake)
+    return calls
+
+
+def test_tpu_platform_picks_the_compiled_kernel(monkeypatch):
+    """The served path's dispatch: off a TPU a quantized dense() runs the
+    XLA reference unless asked for the (interpreted) kernel; on a TPU it
+    always runs the compiled kernel, with no flag from the caller."""
+    from repro.models import modules as nn
+    pqt = _small_prepared(np.random.default_rng(3))
+    x = jnp.ones((2, pqt.cols), jnp.float32)
+    calls = _record_kernel_calls(monkeypatch)
+
+    assert ops.kernel_mode() == (False, True)
+    assert ops.kernel_mode("kernel") == (True, True)
+    nn.dense({"kernel": pqt}, x)
+    assert calls == []                      # CPU default: the reference
+    with nn.quant_mode("kernel"):
+        nn.dense({"kernel": pqt}, x)
+    assert calls[-1]["interpret"] is True
+
+    monkeypatch.setattr(dm, "_platform", lambda: "tpu")
+    assert ops.kernel_mode() == ops.kernel_mode("kernel") == (True, False)
+    nn.dense({"kernel": pqt}, x)
+    assert len(calls) == 2 and calls[-1]["interpret"] is False
+
+
+@pytest.mark.parametrize("via", ["reference", "interpret"])
+def test_tpu_platform_refuses_interpret_and_reference(monkeypatch, via):
+    """On a TPU, asking for the XLA reference (at the decision point and
+    through dense()) or running the kernel in Pallas interpret mode
+    raises."""
+    from repro.models import modules as nn
+    pqt = _small_prepared(np.random.default_rng(4))
+    x = jnp.ones((2, pqt.cols), jnp.float32)
+    monkeypatch.setattr(dm, "_platform", lambda: "tpu")
+    if via == "reference":
+        with pytest.raises(ValueError, match="compiled kernel"):
+            ops.kernel_mode("ref")
+        with nn.quant_mode("ref"), \
+                pytest.raises(ValueError, match="compiled kernel"):
+            nn.dense({"kernel": pqt}, x)
+    else:
+        g = pqt.groups[0]
+        with pytest.raises(ValueError, match="interpret mode"):
+            dm.dequant_matmul(x, g.planes, g.codebook, None, None,
+                              bits=g.bits, n=pqt.n_padded, bm=8, bn=pqt.bn,
+                              bk=g.bk, interpret=True)
 
 
 def test_block_shape_sweep():
@@ -102,6 +170,6 @@ def test_block_shape_sweep():
     y_ref = ref_lib.ref_dequant_matmul(x, packed, cb, None, None, bits=2, n=n)
     for bm, bn, bk in [(8, 32, 128), (16, 64, 256), (128, 128, 128)]:
         y = ops.stripe_matmul(x, packed, cb, None, None, bits=2, n=n,
-                              bm=bm, bn=bn, bk=bk, interpret=True)
+                              bm=bm, bn=bn, bk=bk)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-3)

@@ -2,6 +2,9 @@
 parity across bit-widths, mixed-precision stripe layouts, outlier configs,
 and odd shapes; plus the launch-count contract — a prepared matmul issues
 exactly one pallas_call per distinct stripe bit-width."""
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +15,8 @@ from repro.core import packing
 from repro.core.quantized import QuantStripe, QuantizedTensor
 from repro.kernels import dequant_matmul as dm
 from repro.kernels import ops, ref as ref_lib
-from repro.kernels.plan import (PreparedQuantizedTensor, prepare_for_inference,
+from repro.kernels.plan import (PreparedQuantizedTensor, launch_bn,
+                                padded_rows, prepare_for_inference,
                                 prepare_tree)
 
 jax.config.update("jax_platform_name", "cpu")
@@ -47,6 +51,13 @@ def _make_qt(rng, rows, stripe_spec, k_out=0):
         out_count=jnp.asarray(cnt), shape=(rows, cols))
 
 
+def _gathered(pqt):
+    """`pqt` with every group taking its x columns through the XLA gather
+    (as a permuted group does) instead of reading raw x blocks."""
+    return dataclasses.replace(pqt, groups=tuple(
+        dataclasses.replace(g, x_start=None) for g in pqt.groups))
+
+
 def _check_parity(qt, m=7, seed=0, atol=1e-3):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.normal(size=(m, qt.cols)).astype(np.float32))
@@ -55,14 +66,14 @@ def _check_parity(qt, m=7, seed=0, atol=1e-3):
                                np.asarray(qt.dequantize()),
                                rtol=1e-6, atol=1e-6)
     y_ref = ref_lib.ref_qmatmul(x, qt)
-    y = ops.qmatmul(x, pqt, use_kernel=True, interpret=True)
+    y = ops.qmatmul(x, pqt, use_kernel=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-4, atol=atol)
-    # the default kernel path folds the gather into the kernel; it must be
-    # BITWISE the pre-fold XLA-gather path at every layout in this suite
-    y_pre = ops.prepared_qmatmul(x, pqt, gather="xla")
+    # aligned groups read raw x blocks; they must be BITWISE the same
+    # groups fed x through the XLA gather, at every layout in this suite
+    y_pre = ops.prepared_qmatmul(x, _gathered(pqt))
     assert np.array_equal(np.asarray(y), np.asarray(y_pre)), \
-        "in-kernel gather diverged bitwise from the XLA-gather path"
+        "aligned x fetch diverged bitwise from the XLA-gather path"
     y_xla = ops.qmatmul(x, pqt, use_kernel=False)
     np.testing.assert_allclose(np.asarray(y_xla), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
@@ -126,20 +137,20 @@ def test_launch_count_is_distinct_bitwidths():
     x = jnp.asarray(rng.normal(size=(5, qt.cols)).astype(np.float32))
 
     before = dm.launch_count
-    y_unprepared = ops.qmatmul(x, qt, use_kernel=True, interpret=True)
+    y_unprepared = ops.qmatmul(x, qt, use_kernel=True)
     unprepared_launches = dm.launch_count - before
     assert unprepared_launches == len(spec)
 
     pqt = prepare_for_inference(qt)
     before = dm.launch_count
-    y_prepared = ops.qmatmul(x, pqt, use_kernel=True, interpret=True)
+    y_prepared = ops.qmatmul(x, pqt, use_kernel=True)
     prepared_launches = dm.launch_count - before
     assert prepared_launches == len({b for b, _ in spec}) == 3
 
-    # folding the gather into the kernel must not change the launch
-    # contract: both gather modes issue one launch per distinct bit-width
+    # how x reaches the kernel must not change the launch contract:
+    # gathered groups issue one launch per distinct bit-width too
     before = dm.launch_count
-    y_pre = ops.prepared_qmatmul(x, pqt, gather="xla")
+    y_pre = ops.prepared_qmatmul(x, _gathered(pqt))
     assert dm.launch_count - before == prepared_launches
     assert np.array_equal(np.asarray(y_prepared), np.asarray(y_pre))
 
@@ -158,14 +169,14 @@ def _with_identity_perm(qt):
 def test_identity_perm_plans_are_x_aligned():
     """Single-bit-width tensors carry an identity column permutation
     (build_quantized_tensor sorts within each bit-class), so their plans
-    must drop per-column indexing entirely: x_start set, x_idx None — the
-    kernel then reads raw x blocks and the matmul is gather-free."""
+    must drop per-column indexing entirely: x_start set — the kernel then
+    reads raw x blocks and the matmul is gather-free."""
     rng = np.random.default_rng(21)
     qt = _with_identity_perm(
         _make_qt(rng, rows=64, stripe_spec=[(3, 200)], k_out=2))
     pqt = _check_parity(qt)
     assert pqt.x_gather_free
-    assert pqt.groups[0].x_start == 0 and pqt.groups[0].x_idx is None
+    assert pqt.groups[0].x_start == 0
 
     # the end-to-end integer-bit recipe really hits this path
     W = rng.normal(size=(64, 96)).astype(np.float32)
@@ -175,24 +186,23 @@ def test_identity_perm_plans_are_x_aligned():
 
 
 def test_permuted_plans_carry_block_index_tables():
-    """Permuted / mixed-precision layouts fall back to per-bk-block index
-    tables: x_idx holds exactly the group's slice of gather_idx (same
-    fused order — the bit-identity contract), padding slots = cols."""
+    """Permuted / mixed-precision layouts are not x-aligned: each group's
+    slice of gather_idx is exactly its stripes' original columns in fused
+    order (the one XLA take that feeds the kernel in "blocked" mode),
+    padding slots = cols, and every slice fills whole bk blocks."""
     rng = np.random.default_rng(22)
     qt = _make_qt(rng, rows=64, stripe_spec=[(2, 80), (4, 48)], k_out=2)
     pqt = _check_parity(qt)
     assert not pqt.x_gather_free
+    perm = np.asarray(qt.col_perm)
     off = 0
-    for g in pqt.groups:
-        assert g.x_start is None and g.x_idx.shape == (g.k_padded // g.bk,
-                                                       g.bk)
-        np.testing.assert_array_equal(
-            np.asarray(g.x_idx).ravel(),
-            np.asarray(pqt.gather_idx[off:off + g.k_padded]))
+    for g, (lo, hi) in zip(pqt.groups, [(0, 80), (80, 128)]):
+        assert g.x_start is None and g.k_padded % g.bk == 0
+        idx = np.asarray(pqt.gather_idx[off:off + g.k_padded])
+        np.testing.assert_array_equal(idx[:g.k_cols], perm[lo:hi])
+        # padded slots point at `cols` (the zero fill), never a real column
+        assert (idx[g.k_cols:] == qt.cols).all()
         off += g.k_padded
-    # padded slots point at `cols` (the zero fill), never at a real column
-    pad = np.asarray(pqt.groups[0].x_idx).ravel()[pqt.groups[0].k_cols:]
-    assert (pad == qt.cols).all()
 
 
 def test_plan_cached_on_tensor_and_prepare_tree():
@@ -229,7 +239,7 @@ def test_layer_stacked_tensor_preparation():
         np.testing.assert_allclose(np.asarray(layer.dequantize()),
                                    np.asarray(qt.dequantize()),
                                    rtol=1e-6, atol=1e-6)
-        y = ops.qmatmul(x, layer, use_kernel=True, interpret=True)
+        y = ops.qmatmul(x, layer, use_kernel=True)
         np.testing.assert_allclose(np.asarray(y),
                                    np.asarray(ref_lib.ref_qmatmul(x, qt)),
                                    rtol=1e-4, atol=1e-3)
@@ -264,7 +274,7 @@ def test_prepared_tensor_is_a_pytree():
 
     @jax.jit
     def f(x, p):
-        return ops.qmatmul(x, p, use_kernel=True, interpret=True)
+        return ops.qmatmul(x, p, use_kernel=True)
 
     y = f(x, pqt)
     np.testing.assert_allclose(np.asarray(y),
@@ -272,3 +282,35 @@ def test_prepared_tensor_is_a_pytree():
                                rtol=1e-4, atol=1e-3)
     leaves = jax.tree_util.tree_leaves(pqt)
     assert all(isinstance(l, jax.Array) for l in leaves)
+
+
+@pytest.mark.parametrize("bn,n_padded,bits,want", [
+    (128, 1536, 2, 128), (128, 1536, 4, 128), (128, 1536, 3, 256),
+    (32, 8960, 2, 128), (32, 8960, 3, 256), (32, 128, 8, 128),
+    (32, 96, 3, 96), (128, 896, 3, 896), (32, 64, 2, 64)])
+def test_launch_tile_is_a_legal_mosaic_block(bn, n_padded, bits, want):
+    """A launch tiles N by the fewest whole plan tiles whose blocks Mosaic
+    accepts: a lane extent that is a multiple of 128 and a multiple of 8
+    packed words in every code plane — or one tile over every row."""
+    got = launch_bn(bn, n_padded, bits)
+    assert got == want and n_padded % got == 0 and got % bn == 0
+    if got != n_padded:
+        assert got % 128 == 0
+        for w in packing.plane_widths(bits):
+            assert (got // (32 // w)) % 8 == 0
+
+
+@pytest.mark.parametrize("rows,bn,widths,want", [
+    (4480, 128, (3,), 4608), (8960, 128, (2, 4), 8960),
+    (1536, 32, (2,), 1536), (128, 32, (2, 4), 128), (96, 16, (3,), 96),
+    (200, 32, (3,), 224), (300, 32, (2,), 384)])
+def test_plan_rows_pad_to_whole_launch_units(rows, bn, widths, want):
+    """Rows pad to whole bn tiles; past one launch unit they pad to whole
+    units, so every launch of the plan takes a legal tile of at most a
+    unit's lcm with bn rows, never all of a large matrix's rows."""
+    n = padded_rows(rows, bn, widths)
+    assert n == want and n % bn == 0
+    for bits in widths:
+        tile = launch_bn(bn, n, bits)
+        assert n % tile == 0
+        assert tile <= (n if rows <= 256 else math.lcm(bn, 256))

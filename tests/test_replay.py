@@ -186,11 +186,14 @@ def test_replay_with_contract_gate_and_telemetry(fp_model):
     validate_report(report)
 
 
-def test_launch_cli_replay_and_exports(tmp_path, capsys):
+def test_launch_cli_replay_and_exports(tmp_path, capsys, monkeypatch):
     """launch/serve.py end to end: --replay-trace drives the engine off a
     JSONL trace, --report-json / --telemetry-trace / --stats emit the
     report, a Perfetto-loadable trace, and the uniform metrics view."""
     from repro.launch import serve as launch_serve
+    # the launcher then leaves the compile cache to the environment, which
+    # this process read at start-up: the test writes nothing into the repo
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     trace_p = tmp_path / "trace.jsonl"
     save_trace(str(trace_p), synthesize_trace(seed=4, steps=10, vocab=64,
                                               max_new=(3, 6)))

@@ -24,6 +24,7 @@ from repro.core import packing
 from repro.core.quantized import QuantStripe, QuantizedTensor
 from repro.dist import sharding as shd
 from repro.kernels.plan import PreparedQuantizedTensor, prepare_for_inference
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -122,11 +123,11 @@ def test_prepared_unit_stacked_leaves_shard_packed_row_axis():
 
 
 def test_x_idx_tables_replicate_with_the_unit():
-    """The per-bk-block x index tables (the in-kernel gather's operand)
-    index the ACTIVATION's K axis, so they replicate like gather_idx —
-    both when the unit shards along N and when it stacks — and aligned
-    plans (identity permutation) carry no tables at all, so the spec tree
-    stays leaf-congruent for device_put either way."""
+    """The x gather index of a permuted plan indexes the ACTIVATION's K
+    axis, so it replicates — both when the unit shards along N and when it
+    stacks — and aligned plans (identity permutation) keep the same leaf
+    structure, so the spec tree stays leaf-congruent for device_put either
+    way."""
     rng = np.random.default_rng(29)
     qt = _make_qt(rng, 128, [(2, 80), (4, 48)], k_out=2)   # permuted
     for stack in (False, True):
@@ -137,7 +138,7 @@ def test_x_idx_tables_replicate_with_the_unit():
         specs = shd.spec_for_quantized(pqt, _ax(model=4))
         found = []
         for path, spec in jax.tree_util.tree_flatten_with_path(specs)[0]:
-            if ".x_idx" in jax.tree_util.keystr(path):
+            if ".gather_idx" in jax.tree_util.keystr(path):
                 found.append(spec)
         assert found and all(s == P() for s in found)
         # spec tree must mirror the unit leaf-for-leaf (device_put contract)
@@ -145,7 +146,7 @@ def test_x_idx_tables_replicate_with_the_unit():
                 == jax.tree_util.tree_structure(
                     jax.tree_util.tree_map(lambda _: P(), pqt)))
 
-    # aligned plan: x_idx is None everywhere, structure still congruent
+    # aligned plan: no gather needed, structure still congruent
     ident = QuantizedTensor(
         stripes=qt.stripes[:1],
         col_perm=jnp.arange(qt.stripes[0].n_cols, dtype=jnp.int32),
@@ -173,6 +174,23 @@ def test_word_unaligned_bn_replicates():
     assert pqt.n_tiles % 2 == 0 and not pqt.shards_whole_tiles(2)
     specs = shd.spec_for_quantized(pqt, _ax(model=2))
     assert all(s == P() for s in jax.tree_util.tree_leaves(specs))
+
+
+def test_shards_keep_legal_launch_tiles():
+    """Each shard launches the kernel over its own rows, so a split must
+    leave every shard a legal launch tile short of all its rows: whole
+    launch units (256 rows with a width-1 plane, else 128) or at most one
+    unit.  768 rows split in two are 384 per shard — whole 128-row units
+    for a 2-bit plan, but neither one nor whole 256-row units for a 3-bit
+    one, which therefore replicates."""
+    rng = np.random.default_rng(17)
+    p2 = prepare_for_inference(_make_qt(rng, 768, [(2, 64)]), bn=128)
+    p3 = prepare_for_inference(_make_qt(rng, 768, [(3, 64)]), bn=128)
+    assert p2.n_tiles == p3.n_tiles == 6
+    assert p2.shards_whole_tiles(2) and not p3.shards_whole_tiles(2)
+    assert all(s == P() for s in jax.tree_util.tree_leaves(
+        shd.spec_for_quantized(p3, _ax(model=2))))
+    assert p3.shards_whole_tiles(6)          # 128 rows a shard: one tile
 
 
 def test_raw_quantized_tensor_replicates_as_a_unit():
@@ -227,7 +245,7 @@ def test_tree_shardings_routes_units_and_stays_leaf_congruent():
                                 bn=32)
     params = {"dense": {"kernel": jnp.zeros((16, 8))},
               "q": {"kernel": pqt}}
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     sh = shd.tree_shardings(params, shd.spec_for_param_serve, None, mesh)
     p_leaves, p_def = jax.tree_util.tree_flatten(params)
     s_leaves, s_def = jax.tree_util.tree_flatten(sh)
