@@ -20,15 +20,18 @@ windows forcing preemption+resume, transient step failures absorbed by
 bounded retry) — the demo must end with every request terminal.
 
 Telemetry / replay (DESIGN.md §13): ``--telemetry`` attaches the
-per-request span recorder (zero overhead when off), ``--replay-trace
+per-request event and engine phase-span recorder (zero overhead when
+off), ``--replay-trace
 trace.jsonl`` drives submissions from a JSONL arrival trace instead of
 ``--requests`` (synthesize one with ``python -m repro.serve.replay``),
 ``--report-json out.json`` writes the end-of-run scheduling report
 (TTFT/TPOT p50/p90/p99, tokens/s/slot, queue/occupancy timelines,
 preemption accounting), ``--telemetry-trace out.json`` writes a
-Chrome/Perfetto ``trace_event`` file (one track per slot — open it at
-ui.perfetto.dev), and ``--stats`` prints every engine counter through
-the ONE uniform metrics registry instead of ad-hoc dicts.
+Chrome/Perfetto ``trace_event`` file (one track per slot and an
+``engine`` track of nested phase spans with GC and compile instants —
+open it at ui.perfetto.dev), and ``--stats`` prints every engine counter,
+the phase totals and the compile and GC counters through the ONE
+uniform metrics registry instead of ad-hoc dicts.
 """
 from __future__ import annotations
 
@@ -187,9 +190,10 @@ def main(argv=None):
     ap.add_argument("--tp", type=int, default=0,
                     help="tensor(model)-parallel mesh size")
     ap.add_argument("--telemetry", action="store_true",
-                    help="attach the per-request span recorder "
+                    help="attach the telemetry recorder "
                          "(serve/telemetry.py) — structured lifecycle "
-                         "events + TTFT/TPOT histograms, host-side only, "
+                         "events, TTFT/TPOT histograms, engine phase "
+                         "spans, compile and GC counters; host-side only, "
                          "zero overhead when off")
     ap.add_argument("--replay-trace", metavar="PATH",
                     help="drive submissions from this JSONL arrival trace "
@@ -203,11 +207,14 @@ def main(argv=None):
     ap.add_argument("--telemetry-trace", metavar="PATH",
                     help="write a Chrome/Perfetto trace_event JSON here — "
                          "one track per slot, spans for prefill/decode/"
-                         "spec/resume; open at ui.perfetto.dev (implies "
-                         "--telemetry)")
+                         "spec/resume, and an engine track of nested "
+                         "phase spans with GC and compile instants; open "
+                         "at ui.perfetto.dev (implies --telemetry)")
     ap.add_argument("--stats", action="store_true",
                     help="print the uniform metrics report at exit (every "
-                         "stats() counter through the metrics registry)")
+                         "stats() counter, and with --telemetry the phase "
+                         "totals and compile/GC counters, through the "
+                         "metrics registry)")
     args = ap.parse_args(argv)
     use_compile_cache()
 

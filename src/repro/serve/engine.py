@@ -398,6 +398,19 @@ class ServingEngine:
                     "launch.quantize.claq_quantize_with_draft)")
         elif draft_params is not None:
             raise ValueError("draft_params given without spec=SpecConfig(...)")
+        self._clock = clock if clock is not None else time.monotonic
+        # Per-request event and phase-span recorder (serve/telemetry.py):
+        # every hook call below is guarded by `is not None`, so a disabled
+        # engine pays one predicate per lifecycle edge or span site and
+        # NOTHING inside the jits — telemetry is host-side by construction
+        # (AST/trace contract rules stay green with it attached).  The
+        # recorder binds THIS engine's injectable clock, so StepClock runs
+        # record deterministic timestamps.  Attached before the weights'
+        # plans are built, so its compile counter sees every program the
+        # engine obtains.
+        self.telemetry = telemetry
+        if telemetry is not None:
+            telemetry.attach(n_slots=n_slots, clock=self._clock)
         # Compile every QuantizedTensor leaf into its ahead-of-time
         # inference plan ONCE; the prepared leaves then flow through the
         # jitted steps with zero per-trace layout work and one kernel
@@ -420,17 +433,6 @@ class ServingEngine:
         self.guards = bool(guards)
         self.faults = faults
         self.on_pressure = on_pressure
-        self._clock = clock if clock is not None else time.monotonic
-        # Per-request span recorder (serve/telemetry.py): every hook call
-        # below is guarded by `is not None`, so a disabled engine pays one
-        # predicate per lifecycle edge and NOTHING inside the jits —
-        # telemetry is host-side by construction (AST/trace contract
-        # rules stay green with it attached).  The recorder binds THIS
-        # engine's injectable clock, so StepClock runs record
-        # deterministic timestamps.
-        self.telemetry = telemetry
-        if telemetry is not None:
-            telemetry.attach(n_slots=n_slots, clock=self._clock)
         self._pressure_limit: Optional[int] = None
         # moe decode rows are router-coupled: a batch-1 resume replay is
         # not bitwise the batched decode, so moe cannot preempt and falls
@@ -484,6 +486,13 @@ class ServingEngine:
         self._step_decode_calls = 0
         self._step_draft_calls = 0
         self._step_verify_tokens = 0
+        # What the root ``engine.step`` span reports (recorder attached):
+        # fresh uids admitted and their prompt tokens, and the step's
+        # event (mode, tokens per uid, slots, step index, extras), emitted
+        # once the span has closed so its ``dur`` is the span's.
+        self._step_admitted: List[int] = []
+        self._step_prompt_tokens = 0
+        self._step_event = None
         # Degradation-ladder knobs the controller drives; nominal values
         # make an uncontrolled engine behave exactly as before.
         self._gamma_eff = spec.gamma if spec is not None else 0
@@ -720,6 +729,15 @@ class ServingEngine:
         if verify_contracts:
             from repro.analysis.artifacts import verify_engine
             self.contract_report = verify_engine(self)
+
+    def _span(self, name: str, **attrs):
+        """A host span around one phase of the engine's work: recorded by
+        the telemetry when one is attached, else only the bare profiler
+        annotation (no record, ~1 us when no trace is running)."""
+        tel = self.telemetry
+        if tel is not None:
+            return tel.span(name, self.engine_steps, **attrs)
+        return jax.profiler.TraceAnnotation(name)
 
     @property
     def clock(self):
@@ -1068,10 +1086,11 @@ class ServingEngine:
                     self.allocator.free(pages)
                 raise
 
+        tel = self.telemetry
         for key, idxs in groups.items():
             bucket = key if batch_safe else key[0]
-            tel = self.telemetry
-            t0 = tel.now() if tel is not None else 0.0
+            uids = [reqs[i].uid for i in idxs]
+            n_tokens = sum(len(reqs[i].prompt) for i in idxs)
             B = len(idxs)
             # The batch size is bucketed too (next power of 2, capped at
             # n_slots): the jit cache is keyed on the (batch, bucket)
@@ -1079,78 +1098,61 @@ class ServingEngine:
             # fresh compiles.  Dummy tail rows prefill garbage that is
             # never inserted.
             Bb = min(1 << (B - 1).bit_length(), self.n_slots)
-            toks = np.zeros((Bb, bucket), np.int32)
-            lens = np.ones((Bb,), np.int32)
-            for r, i in enumerate(idxs):
-                toks[r, :len(reqs[i].prompt)] = reqs[i].prompt
-                lens[r] = len(reqs[i].prompt)
-            self.bucketing.record(Bb, bucket)
-            cache_b = api.make_cache(self.cfg, Bb, self.max_len,
-                                     dtype=self._cache_dtype)
-            self.sentinel.observe("prefill", (Bb, bucket))
-            with self._mesh_scope():
-                logits, cache_b, nf = self._prefill(
-                    self.params, jnp.asarray(toks), cache_b,
-                    jnp.asarray(lens))
-                if self.spec is not None:
-                    # the draft needs the prompt in ITS cache too (its
-                    # first proposal continues from the target-sampled
-                    # first token); the draft prefill's logits are unused
-                    dcache_b = api.make_cache(self.cfg, Bb, self.max_len,
-                                              dtype=self._cache_dtype)
-                    self.sentinel.observe("draft_prefill", (Bb, bucket))
-                    dcache_b = self._draft_prefill(
-                        self.draft_params, jnp.asarray(toks), dcache_b)
-            self._step_prefill_tokens += Bb * bucket * (
-                2 if self.spec is not None else 1)
-            if self._kv_int8_admission:
-                # kv_int8 degradation rung: admit through int8 resident-page
-                # numerics.  Skip the fake-quant when the pool is already
-                # int8 (insertion quantizes anyway); always mark the request
-                # so preemption treats it as non-resumable.
-                for i in idxs:
-                    reqs[i].kv_int8 = True
-                if self.kv_dtype != "int8":
-                    cache_b = self._fake_quant_frag(cache_b)
-                    if self.spec is not None:
-                        dcache_b = self._fake_quant_frag(dcache_b)
-            firsts = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            nf_h = np.asarray(nf) if nf is not None else None
-            slots = [self.free.pop(0) for _ in idxs]
-            if self._paged:
-                true_lens = lens[:B].tolist()
-                wrows = np.stack([plans[reqs[i].uid][2] for i in idxs])
-                self.cache = self._paged_insert(self.cache, cache_b, slots,
-                                                true_lens, wrows)
-                if self.spec is not None:
-                    self.draft_cache = self._paged_insert(
-                        self.draft_cache, dcache_b, slots, true_lens, wrows)
+            with self._span("engine.admit", uids=uids, bucket=bucket,
+                            rows=Bb, prompt_tokens=n_tokens) as span:
+                toks = np.zeros((Bb, bucket), np.int32)
+                lens = np.ones((Bb,), np.int32)
                 for r, i in enumerate(idxs):
-                    req = reqs[i]
-                    pages, row, _ = plans[req.uid]
-                    self._tables[slots[r]] = row
-                    self._req_pages[req.uid] = list(pages)
-                    # fake-quantized prefixes must never be shared with
-                    # nominal admissions (on an int8 pool every insert is
-                    # quantized identically, so sharing stays sound there)
-                    if (self.prefix_registry is not None
-                            and not (req.kv_int8
-                                     and self.kv_dtype != "int8")):
-                        self.prefix_registry.register(req.prompt, pages)
-                    self._note_page_peaks(req)
-                self._tables_dirty = True
-            else:
-                self.cache = _masked_group_insert(
-                    self.cache, cache_b, slots, lens[:B].tolist(),
-                    self.bucketing.enabled)
-                if self.spec is not None:
-                    self.draft_cache = _masked_group_insert(
-                        self.draft_cache, dcache_b, slots, lens[:B].tolist(),
-                        self.bucketing.enabled)
-            self._repin_cache()
+                    toks[r, :len(reqs[i].prompt)] = reqs[i].prompt
+                    lens[r] = len(reqs[i].prompt)
+                self.bucketing.record(Bb, bucket)
+                with self._span("engine.admit.fragment"):
+                    cache_b = api.make_cache(self.cfg, Bb, self.max_len,
+                                             dtype=self._cache_dtype)
+                self.sentinel.observe("prefill", (Bb, bucket))
+                with self._span("engine.admit.prefill"), self._mesh_scope():
+                    logits, cache_b, nf = self._prefill(
+                        self.params, jnp.asarray(toks), cache_b,
+                        jnp.asarray(lens))
+                    if self.spec is not None:
+                        # the draft needs the prompt in ITS cache too (its
+                        # first proposal continues from the target-sampled
+                        # first token); the draft prefill's logits are
+                        # unused
+                        dcache_b = api.make_cache(self.cfg, Bb, self.max_len,
+                                                  dtype=self._cache_dtype)
+                        self.sentinel.observe("draft_prefill", (Bb, bucket))
+                        dcache_b = self._draft_prefill(
+                            self.draft_params, jnp.asarray(toks), dcache_b)
+                self._step_prefill_tokens += Bb * bucket * (
+                    2 if self.spec is not None else 1)
+                if self._kv_int8_admission:
+                    # kv_int8 degradation rung: admit through int8
+                    # resident-page numerics.  Skip the fake-quant when the
+                    # pool is already int8 (insertion quantizes anyway);
+                    # always mark the request so preemption treats it as
+                    # non-resumable.
+                    for i in idxs:
+                        reqs[i].kv_int8 = True
+                    if self.kv_dtype != "int8":
+                        cache_b = self._fake_quant_frag(cache_b)
+                        if self.spec is not None:
+                            dcache_b = self._fake_quant_frag(dcache_b)
+                with self._span("engine.admit.first_token"):
+                    firsts = np.asarray(jnp.argmax(logits, axis=-1),
+                                        np.int32)
+                    nf_h = np.asarray(nf) if nf is not None else None
+                slots = [self.free.pop(0) for _ in idxs]
+                with self._span("engine.admit.insert"):
+                    self._insert_group(reqs, idxs, plans, slots, lens[:B],
+                                       cache_b,
+                                       dcache_b if self.spec is not None
+                                       else None)
             if tel is not None:
-                tel.on_admit([reqs[i].uid for i in idxs], slots, bucket,
-                             Bb, tel.now() - t0, self.engine_steps)
+                tel.on_admit(uids, slots, bucket, Bb,
+                             span["end"] - span["start"], self.engine_steps)
+                self._step_admitted.extend(uids)
+                self._step_prompt_tokens += n_tokens
             for r, i in enumerate(idxs):
                 req = reqs[i]
                 req.slot = slots[r]
@@ -1162,6 +1164,45 @@ class ServingEngine:
                     self._quarantine(req, "prefill", int(nf_h[r]))
                 else:
                     self._append_token(req, int(firsts[r]))
+
+    def _insert_group(self, reqs: List[Request], idxs: List[int],
+                      plans: Dict[int, Any], slots: List[int], lens,
+                      cache_b, dcache_b) -> None:
+        """Write an admitted group's prefill fragment(s) into the batched
+        cache(s) at ``slots`` — paged: scatter into the reserved pages,
+        record the table rows and register the prefixes; contiguous: the
+        masked group insert — and re-pin the cache."""
+        if self._paged:
+            true_lens = lens.tolist()
+            wrows = np.stack([plans[reqs[i].uid][2] for i in idxs])
+            self.cache = self._paged_insert(self.cache, cache_b, slots,
+                                            true_lens, wrows)
+            if dcache_b is not None:
+                self.draft_cache = self._paged_insert(
+                    self.draft_cache, dcache_b, slots, true_lens, wrows)
+            for r, i in enumerate(idxs):
+                req = reqs[i]
+                pages, row, _ = plans[req.uid]
+                self._tables[slots[r]] = row
+                self._req_pages[req.uid] = list(pages)
+                # fake-quantized prefixes must never be shared with
+                # nominal admissions (on an int8 pool every insert is
+                # quantized identically, so sharing stays sound there)
+                if (self.prefix_registry is not None
+                        and not (req.kv_int8
+                                 and self.kv_dtype != "int8")):
+                    self.prefix_registry.register(req.prompt, pages)
+                self._note_page_peaks(req)
+            self._tables_dirty = True
+        else:
+            self.cache = _masked_group_insert(
+                self.cache, cache_b, slots, lens.tolist(),
+                self.bucketing.enabled)
+            if dcache_b is not None:
+                self.draft_cache = _masked_group_insert(
+                    self.draft_cache, dcache_b, slots, lens.tolist(),
+                    self.bucketing.enabled)
+        self._repin_cache()
 
     # ------------------------------------------------------- chunked prefill
     def _admit_chunked(self, reqs: List[Request]) -> None:
@@ -1691,6 +1732,10 @@ class ServingEngine:
         preempt the lowest-priority/youngest running victim.  ``step()``
         calls this first, so a driver that only ever calls ``step()``
         still drives every request to a terminal state."""
+        with self._span("engine.pump"):
+            self._pump()
+
+    def _pump(self) -> None:
         step_idx = self.engine_steps
         now = self._clock()
         for req in list(self.active.values()):
@@ -1852,7 +1897,29 @@ class ServingEngine:
         self._step_decode_calls = 0
         self._step_draft_calls = 0
         self._step_verify_tokens = 0
-        out = self._step_inner()
+        tel = self.telemetry
+        root = jax.profiler.StepTraceAnnotation("engine.step",
+                                                step_num=self.engine_steps)
+        if tel is None:
+            with root:
+                out = self._step_inner()
+        else:
+            self._step_admitted = []
+            self._step_prompt_tokens = 0
+            self._step_event = None
+            with tel.span("engine.step", self.engine_steps,
+                          annotation=root) as span:
+                out = self._step_inner()
+                ev = self._step_event
+                span["attrs"].update(
+                    mode=ev[0] if ev is not None else "idle",
+                    active=len(ev[2]) if ev is not None else 0,
+                    admitted=self._step_admitted,
+                    prompt_tokens=self._step_prompt_tokens)
+            if ev is not None:
+                mode, counts, slot_of, step_idx, extra = ev
+                tel.on_step(mode, counts, slot_of,
+                            span["end"] - span["start"], step_idx, **extra)
         if self.cost_model is not None:
             self.last_step_cost_ms = self.cost_model.cost_ms(
                 prefill_tokens=self._step_prefill_tokens,
@@ -1899,40 +1966,44 @@ class ServingEngine:
             return self._spec_step()
         step_idx = self.engine_steps
         slot_of = {uid: r.slot for uid, r in self.active.items()}
-        t0 = tel.now() if tel is not None else 0.0
-        toks = jnp.asarray(self.last_token, jnp.int32)
-        iv = self._inject_vec()
-        self.sentinel.observe("decode", (self.n_slots, iv is not None))
-        with self._mesh_scope():
-            logits, self.cache, nf = self._decode(self.params, toks,
-                                                  self.cache, iv)
-            if self.spec is not None:
-                # spec-off degradation rung keep-warm: advance the draft
-                # cache with the SAME token so both caches stay uniformly
-                # filled and re-enabling speculation is seamless
-                self.sentinel.observe("draft_decode", (self.n_slots,))
-                _, self.draft_cache = self._draft_decode(
-                    self.draft_params, toks, self.draft_cache)
-                self._step_draft_calls += 1
-        self._step_decode_calls += 1
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        nf_h = np.asarray(nf) if nf is not None else None
-        emitted = {}
-        for uid, req in list(self.active.items()):
-            s = req.slot
-            if nf_h is not None and nf_h[s] > 0:
-                # non-finite row: quarantine ONLY this request; the other
-                # rows of the same batched decode are unaffected
-                self._quarantine(req, "decode", int(nf_h[s]))
-                continue
-            t = int(nxt[s])
-            emitted[uid] = t
-            self._append_token(req, t)
-        self.engine_steps += 1
-        self.emitted_tokens += len(emitted)
+        with self._span("engine.decode.dispatch"):
+            toks = jnp.asarray(self.last_token, jnp.int32)
+            iv = self._inject_vec()
+            self.sentinel.observe("decode", (self.n_slots, iv is not None))
+            with self._mesh_scope():
+                logits, self.cache, nf = self._decode(self.params, toks,
+                                                      self.cache, iv)
+                if self.spec is not None:
+                    # spec-off degradation rung keep-warm: advance the
+                    # draft cache with the SAME token so both caches stay
+                    # uniformly filled and re-enabling speculation is
+                    # seamless
+                    self.sentinel.observe("draft_decode", (self.n_slots,))
+                    _, self.draft_cache = self._draft_decode(
+                        self.draft_params, toks, self.draft_cache)
+                    self._step_draft_calls += 1
+            self._step_decode_calls += 1
+        # the host copy waits for the decode: the engine's one sync a step
+        with self._span("engine.decode.sync"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            nf_h = np.asarray(nf) if nf is not None else None
+        with self._span("engine.decode.emit"):
+            emitted = {}
+            for uid, req in list(self.active.items()):
+                s = req.slot
+                if nf_h is not None and nf_h[s] > 0:
+                    # non-finite row: quarantine ONLY this request; the
+                    # other rows of the same batched decode are unaffected
+                    self._quarantine(req, "decode", int(nf_h[s]))
+                    continue
+                t = int(nxt[s])
+                emitted[uid] = t
+                self._append_token(req, t)
+            self.engine_steps += 1
+            self.emitted_tokens += len(emitted)
         if tel is not None:
-            tel.on_step("decode", {u: 1 for u in emitted}, slot_of,
-                        tel.now() - t0, step_idx)
+            self._step_event = ("decode", {u: 1 for u in emitted}, slot_of,
+                                step_idx, {})
         return emitted
 
     def _spec_step(self) -> Dict[int, List[int]]:
@@ -1954,7 +2025,6 @@ class ServingEngine:
         step_idx = self.engine_steps
         slot_of = {uid: r.slot for uid, r in self.active.items()}
         accepted_ks: List[int] = []
-        t0 = tel.now() if tel is not None else 0.0
         # per-slot fill BEFORE the window: prompt + appended tokens minus
         # the pending last_token (whose K/V the window itself writes)
         base_fill = {uid: self._fill(r) for uid, r in self.active.items()}
@@ -2033,10 +2103,10 @@ class ServingEngine:
             self.draft_cache = self._rollback(self.draft_cache, lens_j)
         self._repin_cache()
         if tel is not None:
-            tel.on_step("spec", {u: len(v) for u, v in emitted.items()},
-                        slot_of, tel.now() - t0, step_idx,
-                        window=speculative.window_summary(gamma,
-                                                          accepted_ks))
+            self._step_event = (
+                "spec", {u: len(v) for u, v in emitted.items()}, slot_of,
+                step_idx,
+                {"window": speculative.window_summary(gamma, accepted_ks)})
             for k in accepted_ks:
                 tel.registry.histogram(
                     "spec_accepted_per_window", lo=0.5,
@@ -2214,9 +2284,13 @@ class ServingEngine:
     def metrics(self) -> MetricsRegistry:
         """The ONE uniform metrics surface: every ``stats()`` number —
         spec counters, paged byte ladder, lifecycle tallies — projected
-        onto the telemetry registry as ``serve.*`` gauges (joining the
-        span-derived histograms/timelines when a recorder is attached).
+        onto the telemetry registry as ``serve.*`` gauges, joining, when a
+        recorder is attached, its histograms/timelines and its phase
+        totals and compile/GC counters as ``telemetry.*`` gauges.
         ``launch/serve.py --stats`` renders this."""
-        reg = (self.telemetry.registry if self.telemetry is not None
-               else MetricsRegistry())
-        return registry_from_stats(self.stats(), reg)
+        tel = self.telemetry
+        if tel is None:
+            return registry_from_stats(self.stats(), MetricsRegistry())
+        registry_from_stats(self.stats(), tel.registry)
+        return registry_from_stats(tel.summary(), tel.registry,
+                                   prefix="telemetry")

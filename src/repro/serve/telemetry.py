@@ -1,14 +1,16 @@
-"""Serving telemetry: metrics registry, per-request span recorder, and
-Chrome/Perfetto trace export (DESIGN.md §13).
+"""Serving telemetry: metrics registry, per-request event recorder,
+engine phase spans, compile and GC counters, and Chrome/Perfetto trace
+export (DESIGN.md §13).
 
-Everything here is pure-Python, host-side, and dependency-free.  The
-engine calls into a ``Telemetry`` object (when one is wired via
-``ServingEngine(telemetry=...)``) strictly OUTSIDE its jitted functions
-— recording an event is a list append plus a couple of dict writes, and
-a disabled engine (``telemetry=None``) pays a single ``is not None``
-check per call site, so the hot path is untouched either way and the
-PR 8 contract rules (no jit side effects, serve-path determinism) stay
-green.
+Everything here is host-side.  The engine calls into a ``Telemetry``
+object (when one is wired via ``ServingEngine(telemetry=...)``) strictly
+OUTSIDE its jitted functions — recording an event or a span is a list
+append plus a couple of dict writes, and a disabled engine
+(``telemetry=None``) pays a single ``is not None`` check per call site,
+so the hot path is untouched either way and the contract rules (no jit
+side effects, serve-path determinism) stay green.  The only JAX this
+module touches is ``jax.profiler`` (span annotations) and
+``jax.monitoring`` (compile events); it adds no device work and no sync.
 
 Clocking: the recorder reads time exclusively through an injectable
 monotonic clock.  By default it binds the ENGINE's clock at attach time
@@ -35,7 +37,7 @@ Metrics model (all pure counters/lists — snapshots are plain JSON):
   buckets/decade), deterministic, O(1) memory regardless of sample
   count.
 
-Span model: per-request lifecycle events (``submit``, ``admit``,
+Event model: per-request lifecycle events (``submit``, ``admit``,
 ``first_token``, ``step`` (decode/spec), ``resume``, ``preempt``,
 ``retire``) each carry the clock time ``t`` AND the engine step index,
 plus a per-uid record (submit/admit/first/last timestamps, tokens_out,
@@ -43,19 +45,38 @@ preemptions, terminal state) from which TTFT/TPOT are derived at
 retirement and fed into the ``ttft_ms`` / ``tpot_ms`` /
 ``queue_wait_ms`` histograms.
 
+Phase spans: ``Telemetry.span(name, step, **attrs)`` is the one span
+primitive.  It opens a ``jax.profiler.TraceAnnotation`` (so the span
+lands in the profiler's host plane, on the device trace's clock) and, on
+exit, appends one record — ``name``, ``id``, ``parent`` (the enclosing
+open span), ``step``, ``start``, ``end``, ``attrs`` — to ``spans``.  It
+adds no sync: a span around a host copy measures the wait the copy
+itself does.  Two counters are recorded under the span open when they
+fire: ``compiles`` (one record per executable JAX obtains, with the
+function name and the trace, lower and compile-or-load seconds that
+``jax.monitoring`` reports) and ``gc_pauses`` (one ``host.gc`` span per
+generation-2 collection, also annotated in the profiler).  Their hooks
+are installed once per process and reach live recorders through weak
+references only.
+
 ``perfetto_trace`` renders the event list as Chrome ``trace_event``
 JSON — one track (tid) per engine slot plus a queue track, "X" complete
 spans for prefill/decode/spec/resume work, instants for
-submit/preempt/retire, and "C" counter tracks for the sampled
-queue-depth / active-slot / page-occupancy timelines — loadable
-directly in ui.perfetto.dev.
+submit/preempt/retire, "C" counter tracks for the sampled
+queue-depth / active-slot / page-occupancy timelines, and one
+``engine`` track of nested phase spans with GC and compile instants —
+loadable directly in ui.perfetto.dev.
 """
 from __future__ import annotations
 
+import gc
 import json
 import math
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+import jax
 
 
 def monotonic() -> float:
@@ -300,26 +321,163 @@ def registry_from_stats(stats: Dict[str, Any],
     return reg
 
 
+# ----------------------------------------------------------- process hooks
+
+# JAX's duration events for obtaining one executable, by stage: tracing
+# to a jaxpr, lowering to an MLIR module, and the backend compile (which
+# also covers a load from the persistent compile cache).  Tracing and
+# lowering come first; the backend compile closes the executable's record.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                "/jax/compilation_cache/cache_misses": "cache_misses"}
+
+# Attached recorders, held weakly: the process-wide hooks below forward to
+# them and hold no reference, so a dropped recorder (and its engine) is
+# collected and simply stops receiving.
+_TraceAnnotation = jax.profiler.TraceAnnotation
+_live: "weakref.WeakSet[Telemetry]" = weakref.WeakSet()
+_hooks_installed = False
+_gc_annotation = None
+# trace and lower seconds of the executable being obtained.  The last
+# trace before a lowering is the outermost one (a trace reports when it
+# ends, after the traces nested in it); a trace that finds its lowering
+# cached obtains no executable and is replaced by the next.
+_pending = {"trace": 0.0, "lower": 0.0}
+
+
+def _on_duration_event(event: str, duration_secs: float, **kw) -> None:
+    stage = COMPILE_EVENTS.get(event)
+    if stage == "trace":
+        _pending["trace"], _pending["lower"] = duration_secs, 0.0
+    elif stage == "lower":
+        _pending["lower"] += duration_secs
+    elif stage == "compile":
+        trace_s, lower_s = _pending["trace"], _pending["lower"]
+        _pending["trace"] = _pending["lower"] = 0.0
+        for tel in list(_live):
+            tel.on_compile(str(kw.get("fun_name", "")), trace_s, lower_s,
+                           duration_secs)
+
+
+def _on_event(event: str, **kw) -> None:
+    key = CACHE_EVENTS.get(event)
+    if key is not None:
+        for tel in list(_live):
+            tel.cache_events[key] += 1
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """``gc.callbacks`` hook: a generation-2 collection becomes a
+    ``host.gc`` span in every live recorder and one profiler annotation,
+    so a stall shows in the trace beside the work it held up."""
+    global _gc_annotation
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        if not _live:
+            return
+        _gc_annotation = _TraceAnnotation("host.gc")
+        _gc_annotation.__enter__()
+        for tel in list(_live):
+            tel._gc_start()
+        return
+    for tel in list(_live):
+        tel._gc_stop()
+    if _gc_annotation is not None:
+        _gc_annotation.__exit__(None, None, None)
+        _gc_annotation = None
+
+
+def _install_hooks() -> None:
+    global _hooks_installed
+    if _hooks_installed:
+        return
+    _hooks_installed = True
+    jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
+    jax.monitoring.register_event_listener(_on_event)
+    gc.callbacks.append(_on_gc)
+
+
 # ------------------------------------------------------------------- spans
 
+class _Span:
+    """Context manager behind ``Telemetry.span``: enters the profiler
+    annotation, pushes a new record on the recorder's open-span stack,
+    and on exit stamps ``end`` and appends the record to ``spans``."""
+
+    __slots__ = ("_tel", "_ann", "_name", "_step", "_attrs", "rec")
+
+    def __init__(self, tel: "Telemetry", ann, name: str, step: int,
+                 attrs: Dict[str, Any]) -> None:
+        self._tel = tel
+        self._ann = ann
+        self._name = name
+        self._step = step
+        self._attrs = attrs
+
+    # The clock is read inline (not through ``Telemetry.now``): under the
+    # profiler's Python tracer every extra frame lengthens the host gap
+    # the span is there to measure.
+    def __enter__(self) -> Dict[str, Any]:
+        self._ann.__enter__()
+        tel = self._tel
+        stack = tel._open
+        sid = tel._next_id
+        tel._next_id = sid + 1
+        clock = tel.clock
+        rec = self.rec = {"name": self._name, "id": sid,
+                          "parent": stack[-1]["id"] if stack else None,
+                          "step": self._step,
+                          "start": clock() if clock is not None else 0.0,
+                          "end": None, "attrs": self._attrs}
+        stack.append(rec)
+        return rec
+
+    def __exit__(self, *exc) -> bool:
+        tel = self._tel
+        rec = self.rec
+        clock = tel.clock
+        rec["end"] = clock() if clock is not None else 0.0
+        tel._open.pop()
+        tel.spans.append(rec)
+        self._ann.__exit__(*exc)
+        return False
+
+
 class Telemetry:
-    """Per-request span recorder + metrics registry for one engine.
+    """Per-request event recorder, phase-span recorder and metrics
+    registry for one engine.
 
     Construct one, pass it as ``ServingEngine(telemetry=...)``; the
     engine attaches it at init (binding its injectable clock unless one
-    was given explicitly) and invokes the ``on_*`` hooks host-side at
-    each lifecycle edge.  All state is plain Python: ``events`` is the
-    ordered structured event stream, ``records`` maps uid -> span record,
-    ``registry`` holds the histograms/timelines the end-of-run report
-    reads.  One Telemetry serves ONE engine — re-attaching is a bug."""
+    was given explicitly), opens ``span``s around its phases and invokes
+    the ``on_*`` hooks host-side at each lifecycle edge.  All state is
+    plain Python: ``events`` is the ordered structured event stream,
+    ``records`` maps uid -> per-request record, ``spans`` holds the
+    closed phase spans, ``compiles`` and ``gc_pauses`` the two counters'
+    records, and ``registry`` the histograms/timelines the end-of-run
+    report reads.  One Telemetry serves ONE engine — re-attaching is a
+    bug."""
 
     def __init__(self, clock: Optional[Callable[[], float]] = None) -> None:
         self.clock = clock
         self.registry = MetricsRegistry()
         self.events: List[Dict[str, Any]] = []
         self.records: Dict[int, Dict[str, Any]] = {}
+        self.spans: List[Dict[str, Any]] = []
+        self.compiles: List[Dict[str, Any]] = []
+        self.gc_pauses: List[Dict[str, Any]] = []
+        self.cache_events = {key: 0 for key in CACHE_EVENTS.values()}
         self.n_slots = 0
         self._attached = False
+        self._open: List[Dict[str, Any]] = []
+        self._next_id = 0
+        self._gc_open: Optional[Tuple[float, Optional[int],
+                                      Optional[int]]] = None
 
     # -- wiring ---------------------------------------------------------
     def attach(self, n_slots: int, clock: Callable[[], float]) -> None:
@@ -331,6 +489,8 @@ class Telemetry:
         self.n_slots = n_slots
         if self.clock is None:
             self.clock = clock
+        _install_hooks()
+        _live.add(self)
 
     def now(self) -> float:
         return self.clock() if self.clock is not None else 0.0
@@ -339,8 +499,72 @@ class Telemetry:
         ev = {"t": self.now(), "step": int(step), "kind": kind}
         ev.update(data)
         self.events.append(ev)
-        self.registry.counter(f"events.{kind}").inc()
         return ev
+
+    # -- phase spans and counters ---------------------------------------
+    def span(self, name: str, step: int, annotation=None,
+             **attrs) -> _Span:
+        """``with tel.span("engine.decode.sync", step) as rec:`` — times
+        the block on the recorder's clock and annotates it in the
+        profiler (``annotation`` overrides the default
+        ``TraceAnnotation(name)``, e.g. with a ``StepTraceAnnotation``).
+        ``rec["attrs"]`` may be filled in inside the block."""
+        return _Span(self, annotation if annotation is not None
+                     else _TraceAnnotation(name), name, step, attrs)
+
+    def _where(self) -> Tuple[Optional[int], Optional[int]]:
+        """(parent span id, step) of the innermost open span."""
+        if not self._open:
+            return None, None
+        top = self._open[-1]
+        return top["id"], top["step"]
+
+    def on_compile(self, fun: str, trace_s: float, lower_s: float,
+                   compile_s: float) -> None:
+        """One executable obtained (traced, lowered, and compiled or
+        loaded from the persistent cache), under the span open when its
+        backend compile happened."""
+        parent, step = self._where()
+        self.compiles.append({
+            "t": self.now(), "fun": fun,
+            "s": float(trace_s + lower_s + compile_s),
+            "trace_s": float(trace_s), "lower_s": float(lower_s),
+            "compile_s": float(compile_s), "step": step, "parent": parent})
+
+    def _gc_start(self) -> None:
+        parent, step = self._where()
+        self._gc_open = (self.now(), parent, step)
+
+    def _gc_stop(self) -> None:
+        if self._gc_open is None:
+            return
+        start, parent, step = self._gc_open
+        self._gc_open = None
+        self.gc_pauses.append({"name": "host.gc", "parent": parent,
+                               "step": step, "start": start,
+                               "end": self.now()})
+
+    def summary(self) -> Dict[str, Any]:
+        """Phase totals (count, total and max ms per span name) and the
+        compile and GC counters — the ``--stats`` report's telemetry
+        part (``ServingEngine.metrics``)."""
+        phase: Dict[str, Dict[str, Any]] = {}
+        for s in self.spans:
+            d = phase.setdefault(s["name"], {"count": 0, "total_ms": 0.0,
+                                             "max_ms": 0.0})
+            ms = (s["end"] - s["start"]) * 1e3
+            d["count"] += 1
+            d["total_ms"] += ms
+            d["max_ms"] = max(d["max_ms"], ms)
+        pauses = [(g["end"] - g["start"]) * 1e3 for g in self.gc_pauses]
+        return {
+            "phase": phase,
+            "compile": dict(programs=len(self.compiles),
+                            seconds=sum(c["s"] for c in self.compiles),
+                            **self.cache_events),
+            "host_gc": {"count": len(pauses), "total_ms": sum(pauses),
+                        "max_ms": max(pauses, default=0.0)},
+        }
 
     # -- lifecycle hooks (called by the engine, host-side only) ---------
     def on_submit(self, req, step: int) -> None:
@@ -371,7 +595,6 @@ class Telemetry:
         self._emit("admit", step, uids=[int(u) for u in uids],
                    slots=[int(s) for s in slots], bucket=int(bucket),
                    batch=int(batch), dur=float(dur))
-        self.registry.histogram("prefill_ms").observe(dur * 1e3)
 
     def on_resume(self, uid: int, slot: int, replayed: int, dur: float,
                   step: int) -> None:
@@ -401,7 +624,6 @@ class Telemetry:
                    tokens=[int(emitted[u]) for u in uids],
                    slots=[int(slots[u]) for u in uids], dur=float(dur),
                    **extra)
-        self.registry.histogram(f"{mode}_step_ms").observe(dur * 1e3)
 
     def on_preempt(self, victims: Sequence[Tuple[int, int]], reason: str,
                    step: int) -> None:
@@ -467,7 +689,9 @@ class Telemetry:
 # Track ids: tid 0 is engine metadata, 1..n_slots the slot tracks,
 # n_slots+1 the queue track, n_slots+2 the admission controller (the
 # controller metadata row appears only when controller events exist, so
-# uncontrolled traces are byte-stable).  Span names by event kind/mode.
+# uncontrolled traces are byte-stable), n_slots+3 the engine's phase
+# spans with GC and compile instants (present when any was recorded).
+# Span names by event kind/mode.
 _SPAN_NAMES = {"admit": "prefill", "resume": "resume",
                "decode": "decode", "spec": "spec"}
 
@@ -481,10 +705,13 @@ def perfetto_trace(tel: Telemetry) -> Dict[str, Any]:
     ``queue`` track; "X" complete events for prefill/resume/decode/spec
     work with their host-measured duration (0-length under a StepClock);
     instant events for submit (queue track), preempt and retire (slot
-    track); "C" counter events for every sampled timeline."""
+    track); "C" counter events for every sampled timeline; an ``engine``
+    track with the phase spans as nested "X" events and ``host.gc`` and
+    ``compile:<fun>`` instants."""
     pid = 1
     qtid = tel.n_slots + 1
     ctid = tel.n_slots + 2
+    etid = tel.n_slots + 3
     evs: List[Dict[str, Any]] = [
         {"ph": "M", "pid": pid, "tid": 0, "name": "process_name",
          "args": {"name": "repro.serve"}},
@@ -558,6 +785,27 @@ def perfetto_trace(tel: Telemetry) -> Dict[str, Any]:
                         "s": "t", "ts": us(t),
                         "args": {"uid": ev["uid"],
                                  "tokens_out": ev["tokens_out"]}})
+    if tel.spans or tel.gc_pauses or tel.compiles:
+        evs.append({"ph": "M", "pid": pid, "tid": etid,
+                    "name": "thread_name", "args": {"name": "engine"}})
+    # parents before children at equal start, so the nesting renders
+    for sp in sorted(tel.spans,
+                     key=lambda s: (s["start"], -s["end"], s["id"])):
+        evs.append({"ph": "X", "pid": pid, "tid": etid, "name": sp["name"],
+                    "cat": "engine", "ts": us(sp["start"]),
+                    "dur": us(sp["end"] - sp["start"]),
+                    "args": dict(sp["attrs"], step=sp["step"])})
+    for g in tel.gc_pauses:
+        evs.append({"ph": "i", "pid": pid, "tid": etid, "name": "host.gc",
+                    "cat": "engine", "s": "t", "ts": us(g["start"]),
+                    "args": {"dur_ms": (g["end"] - g["start"]) * 1e3,
+                             "step": g["step"]}})
+    for c in tel.compiles:
+        evs.append({"ph": "i", "pid": pid, "tid": etid,
+                    "name": f"compile:{c['fun']}", "cat": "engine",
+                    "s": "t", "ts": us(c["t"]),
+                    "args": {k: c[k] for k in ("s", "trace_s", "lower_s",
+                                               "compile_s", "step")}})
     for name in tel.registry.names():
         m = tel.registry.get(name)
         if isinstance(m, Timeline):

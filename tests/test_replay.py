@@ -210,7 +210,11 @@ def test_launch_cli_replay_and_exports(tmp_path, capsys, monkeypatch):
     doc = json.loads(pf_p.read_text())
     tracks = [e["args"]["name"] for e in doc["traceEvents"]
               if e["ph"] == "M" and e["name"] == "thread_name"]
-    assert sorted(tracks) == ["queue", "slot 0", "slot 1"]
+    assert sorted(tracks) == ["engine", "queue", "slot 0", "slot 1"]
     out = capsys.readouterr().out
     assert "[serve metrics]" in out
     assert "serve.lifecycle.finished" in out
+    # --stats carries the phase totals and the compile and GC counters
+    assert "telemetry.phase.engine.decode.sync.count" in out
+    assert "telemetry.compile.programs" in out
+    assert "telemetry.host_gc.count" in out

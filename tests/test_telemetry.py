@@ -11,8 +11,10 @@ Perfetto rendering is a loadable trace_event document with one named
 track per slot plus a queue track.
 """
 import dataclasses
+import gc
 import json
 import math
+import weakref
 
 import jax
 import pytest
@@ -22,6 +24,7 @@ from repro.models import api
 from repro.serve import (Histogram, MetricsRegistry, ServingEngine,
                          SpecConfig, StepClock, Telemetry, perfetto_trace,
                          registry_from_stats)
+from repro.serve import telemetry as telemetry_mod
 from repro.serve.telemetry import Timeline
 
 jax.config.update("jax_platform_name", "cpu")
@@ -170,8 +173,159 @@ def test_engine_emits_full_lifecycle_spans(fp_model):
 
 def test_telemetry_is_observation_only(fp_model):
     base = _run(_engine(fp_model))
-    instrumented = _run(_engine(fp_model, telemetry=Telemetry()))
+    tel = Telemetry()
+    eng = _engine(fp_model, telemetry=tel)
+    instrumented = _run(eng)
     assert instrumented == base
+    # the instrumented run really recorded its phases: one root per step
+    roots = [s for s in tel.spans if s["name"] == "engine.step"]
+    assert roots and tel.spans and tel.compiles
+    assert len({s["step"] for s in roots}) == eng.engine_steps
+
+
+class TickClock:
+    """A clock that moves 1 ms on every read, so each span gets a
+    distinct, deterministic, non-zero duration."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1e-3
+        return self.t
+
+
+def _children(tel, span):
+    return [s["name"] for s in tel.spans if s["parent"] == span["id"]]
+
+
+def _by_id(tel):
+    return {s["id"]: s for s in tel.spans}
+
+
+def test_decode_step_spans_nest_under_root(fp_model):
+    tel = Telemetry()
+    eng = _engine(fp_model, telemetry=tel)
+    eng.add_requests(PROMPTS[:2], max_new_tokens=4)
+    n = len(tel.spans)
+    eng.step()
+    new = tel.spans[n:]
+    roots = [s for s in new if s["name"] == "engine.step"]
+    assert len(roots) == 1
+    root = roots[0]
+    assert root["parent"] is None and root["step"] == 0
+    assert root["attrs"] == {"mode": "decode", "active": 2, "admitted": [],
+                             "prompt_tokens": 0}
+    assert _children(tel, root) == ["engine.pump", "engine.decode.dispatch",
+                                    "engine.decode.sync",
+                                    "engine.decode.emit"]
+    # every span of the step is a direct child of the root, on its step,
+    # and under a StepClock every duration is exactly 0
+    assert all(s["parent"] == root["id"] for s in new if s is not root)
+    assert all(s["step"] == 0 and s["start"] == s["end"] == 0.0
+               for s in new)
+
+
+def test_admission_spans_carry_group_and_durations(fp_model):
+    tel = Telemetry(clock=TickClock())
+    eng = _engine(fp_model, telemetry=tel)
+    uids = [eng.submit(p, max_new_tokens=4) for p in PROMPTS[:2]]
+    eng.step()
+    admits = [s for s in tel.spans if s["name"] == "engine.admit"]
+    assert len(admits) == 1
+    adm = admits[0]
+    # prompts of 3 and 4 tokens share bucket 8 in one 2-row prefill
+    assert adm["attrs"] == {"uids": uids, "bucket": 8, "rows": 2,
+                            "prompt_tokens": 7}
+    assert _children(tel, adm) == ["engine.admit.fragment",
+                                   "engine.admit.prefill",
+                                   "engine.admit.first_token",
+                                   "engine.admit.insert"]
+    ids = _by_id(tel)
+    pump = ids[adm["parent"]]
+    root = ids[pump["parent"]]
+    assert pump["name"] == "engine.pump" and root["name"] == "engine.step"
+    assert root["attrs"]["admitted"] == uids
+    assert root["attrs"]["prompt_tokens"] == 7
+    # children lie inside their parent on the recorder's clock
+    for s in tel.spans:
+        if s["parent"] is not None:
+            p = ids[s["parent"]]
+            assert p["start"] < s["start"] <= s["end"] < p["end"]
+    # the admit and step events take their durations from the spans
+    ev_admit = [e for e in tel.events if e["kind"] == "admit"][0]
+    ev_step = [e for e in tel.events if e["kind"] == "step"][0]
+    assert ev_admit["dur"] == adm["end"] - adm["start"] > 0
+    assert ev_step["dur"] == root["end"] - root["start"] > 0
+
+
+def test_span_streams_are_byte_identical_across_seeded_runs(fp_model):
+    def run():
+        tel = Telemetry()
+        _run(_engine(fp_model, telemetry=tel))
+        return tel.spans
+
+    s1, s2 = run(), run()
+    assert len(s1) > 10
+    assert json.dumps(s1) == json.dumps(s2)
+
+
+def test_new_bucket_compiles_under_prefill_span(fp_model):
+    tel = Telemetry()
+    eng = _engine(fp_model, telemetry=tel)
+    eng.add_requests([[1, 2, 3]], max_new_tokens=1)      # bucket 8
+    n0 = len(tel.compiles)
+    eng.add_requests([list(range(1, 11))], max_new_tokens=1)   # bucket 16
+    prefill = [s for s in tel.spans
+               if s["name"] == "engine.admit.prefill"][-1]
+    under = [c for c in tel.compiles[n0:] if c["parent"] == prefill["id"]]
+    assert [c["fun"] for c in under] == ["jit(_prefill_fn)"]
+    rec = under[0]
+    assert rec["step"] == prefill["step"]
+    assert min(rec["trace_s"], rec["lower_s"], rec["compile_s"]) > 0
+    assert rec["s"] == pytest.approx(
+        rec["trace_s"] + rec["lower_s"] + rec["compile_s"])
+    # the same (rows, bucket) shape again obtains no program at all
+    n1 = len(tel.compiles)
+    eng.add_requests([list(range(1, 13))], max_new_tokens=1)   # bucket 16
+    assert tel.compiles[n1:] == []
+    summ = tel.summary()["compile"]
+    assert summ["programs"] == len(tel.compiles)
+    assert summ["seconds"] == pytest.approx(sum(c["s"]
+                                                for c in tel.compiles))
+
+
+def test_gen2_collection_is_a_host_gc_child_span():
+    tel = Telemetry()
+    tel.attach(n_slots=1, clock=StepClock(10.0))
+    with tel.span("outer", 3) as outer:
+        gc.collect(2)
+    pauses = [g for g in tel.gc_pauses if g["parent"] == outer["id"]]
+    assert len(pauses) == 1
+    assert pauses[0]["name"] == "host.gc" and pauses[0]["step"] == 3
+    # a younger generation is not a pause this counter records
+    n = len(tel.gc_pauses)
+    gc.collect(0)
+    assert len(tel.gc_pauses) == n
+    assert tel.summary()["host_gc"]["count"] == n
+
+
+def test_dropped_recorder_leaves_no_reference(fp_model):
+    from jax._src import monitoring as jax_monitoring
+    tel = Telemetry()
+    eng = _engine(fp_model, telemetry=tel)
+    _run(eng)
+    tel_ref, eng_ref = weakref.ref(tel), weakref.ref(eng)
+    del tel, eng
+    gc.collect()
+    assert tel_ref() is None and eng_ref() is None
+    # the process hooks are installed once, however many recorders came
+    assert gc.callbacks.count(telemetry_mod._on_gc) == 1
+    assert jax_monitoring.get_event_duration_listeners().count(
+        telemetry_mod._on_duration_event) == 1
+    assert jax_monitoring.get_event_listeners().count(
+        telemetry_mod._on_event) == 1
+    assert all(t is not tel_ref() for t in telemetry_mod._live)
 
 
 def test_preempt_resume_events_and_accounting(fp_model):
@@ -227,10 +381,10 @@ def test_perfetto_trace_structure(fp_model):
     doc = perfetto_trace(tel)
     json.loads(json.dumps(doc))            # valid JSON document
     evs = doc["traceEvents"]
-    tracks = [e["args"]["name"] for e in evs
-              if e["ph"] == "M" and e["name"] == "thread_name"]
-    assert sorted(tracks) == ["queue", "slot 0", "slot 1"]
-    spans = [e for e in evs if e["ph"] == "X"]
+    tracks = {e["args"]["name"]: e["tid"] for e in evs
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert sorted(tracks) == ["engine", "queue", "slot 0", "slot 1"]
+    spans = [e for e in evs if e["ph"] == "X" and e["cat"] == "serve"]
     assert spans and all(e["dur"] >= 0 and e["ts"] >= 0 for e in spans)
     assert {e["name"] for e in spans} <= {"prefill", "decode", "spec",
                                           "resume"}
@@ -241,6 +395,16 @@ def test_perfetto_trace_structure(fp_model):
     assert any(n.startswith("retire:") for n in names)
     counters = [e for e in evs if e["ph"] == "C"]
     assert {"queue_depth", "active_slots"} <= {e["name"] for e in counters}
+    # phase spans: one X event per recorded span, all on the engine track
+    phases = [e for e in evs if e["ph"] == "X" and e["cat"] == "engine"]
+    assert len(phases) == len(tel.spans)
+    assert all(e["tid"] == tracks["engine"] for e in phases)
+    assert {"engine.step", "engine.decode.sync",
+            "engine.admit.prefill"} <= {e["name"] for e in phases}
+    compiles = [e for e in evs if e["ph"] == "i"
+                and e["name"].startswith("compile:")]
+    assert len(compiles) == len(tel.compiles)
+    assert all(e["tid"] == tracks["engine"] for e in compiles)
 
 
 def test_engine_metrics_consolidates_stats(fp_model):
