@@ -64,8 +64,8 @@ def _quantized_types():
 # leaf fields of QuantizedTensor / PreparedQuantizedTensor / PlanGroup — a
 # per-leaf rule must never invent a spec for these (see spec_for_quantized)
 _QUANT_LEAF_MARKERS = (".groups[", ".planes[", ".gather_idx", ".codebook",
-                      ".out_idx", ".out_val", ".stripes[", ".col_perm",
-                      ".out_count", ".packed")
+                      ".out_idx", ".out_val", ".out_win", ".stripes[",
+                      ".col_perm", ".out_count", ".packed")
 
 
 def spec_for_quantized(q, ax: MeshAxes):
@@ -79,6 +79,9 @@ def spec_for_quantized(q, ax: MeshAxes):
       * `codebook` / `out_idx` / `out_val` are K-indexed (and outlier idx
         *values* are global row numbers), `gather_idx` indexes the
         activation's K axis — all replicated;
+      * `out_win`, the outlier slot windows per (bn-row tile, K block),
+        flat and tile-major, splits on its last axis with the planes'
+        rows, so each shard holds the windows of its own tiles;
       * guarded by `shards_whole_tiles(model_size)`: when the tile count
         does not divide, the WHOLE unit stays replicated — never torn;
       * stacked (L, ...) / (L, E, ...) leaves (launch.quantize stacks
@@ -98,14 +101,16 @@ def spec_for_quantized(q, ax: MeshAxes):
         model = ax.model
 
         def one(path, leaf):
-            field = getattr(path[-2] if len(path) > 1 else path[-1],
-                            "name", None)
-            if field == "planes":
-                ndim = np.ndim(leaf)
-                entries = [None] * ndim
-                entries[ndim - 2] = model
-                return PartitionSpec(*entries)
-            return PartitionSpec()
+            # the leaf's field: its last attribute key (a plane sits one
+            # tuple index below `planes`)
+            field = next((k.name for k in reversed(path)
+                          if hasattr(k, "name")), None)
+            axis = {"planes": -2, "out_win": -1}.get(field)
+            if axis is None:
+                return PartitionSpec()
+            entries = [None] * np.ndim(leaf)
+            entries[axis] = model
+            return PartitionSpec(*entries)
 
         return jax.tree_util.tree_map_with_path(one, q)
 
@@ -121,7 +126,9 @@ def sharded_qmatmul(x, pqt, mesh, *, act_dtype=None):
     over "model" (a unit that does not split whole stays replicated) and
     x's leading rows over "dp" where they divide.  Outlier row ids are
     global, so each shard shifts them into its local rows; ids of other
-    shards then match no local row.  Every output element is the same
+    shards then match no local row.  The shift keeps each column's slots
+    in row order, and each shard holds the slot windows of its own tiles,
+    so the windows stay valid.  Every output element is the same
     contraction as on one device, so the result is bitwise the unsharded
     kernel's."""
     import dataclasses

@@ -12,8 +12,13 @@ TPU adaptation (DESIGN.md §4):
     2**bits-way select-accumulate (no gather — codebooks are <=16 entries,
     so a select chain beats any gather on TPU and vectorizes across the
     whole tile);
-  * outliers apply inside the dequant epilogue as `k_out` masked selects
-    against the tile's global row ids — no scatter, shape-static;
+  * outliers apply inside the dequant epilogue as masked selects against
+    the tile's global row ids — no scatter, shape-static — over only the
+    slots that can hold a row of the tile: each column's slots ascend by
+    row, and a scalar-prefetched window table (`out_win`, built by
+    kernels/plan.py) gives per (N tile, K block) the slots [lo, hi) to
+    visit, in aligned SLOT_CHUNK-slot chunks.  Without a table the window
+    is every slot;
   * the weight tile feeds the MXU directly from VMEM; full-width W never
     exists in HBM.
 
@@ -57,6 +62,9 @@ Array = jax.Array
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
+# outlier slots per epilogue step: one aligned (8, bk) sublane tile of
+# out_idx / out_val, then 8 unrolled selects; window bounds are multiples
+SLOT_CHUNK = 8
 
 
 def _platform() -> str:
@@ -90,9 +98,43 @@ def _dequant_tile(codes, cb, n_levels: int):
     return w
 
 
-def _kernel(x_ref, *rest, bits: int, plane_widths: Sequence[int], bn: int,
-            bk: int, k_out: int, n_levels: int, has_acc: bool, compute_dtype,
-            x_mode: str, k_cols: int, has_scale: bool):
+def _outlier_epilogue(wt_ref, idx_ref, val_ref, lo, hi, row_ids,
+                      k_out: int):
+    """Override the dequantized tile in `wt_ref` with the outliers of the
+    slots [lo, hi) (multiples of SLOT_CHUNK; hi may pass k_out).  Whole
+    chunks run in a loop over the window; the last k_out % SLOT_CHUNK
+    slots, which no aligned chunk covers, run once where the window
+    reaches them.  Every slot outside the window matches no row of the
+    tile, so the result is bitwise that of visiting every slot."""
+    def apply(idx, val):
+        w = wt_ref[...]
+        for r in range(idx.shape[0]):
+            w = jnp.where(idx[r:r + 1, :] == row_ids, val[r:r + 1, :], w)
+        wt_ref[...] = w
+
+    c = SLOT_CHUNK
+    whole = k_out // c * c
+    if whole:
+        def chunk(i, carry):
+            s = pl.multiple_of(i * c, c)
+            apply(idx_ref[pl.ds(s, c), :], val_ref[pl.ds(s, c), :])
+            return carry
+
+        jax.lax.fori_loop(lo // c, jnp.minimum(hi, whole) // c, chunk, 0)
+    if whole < k_out:
+        @pl.when(hi > whole)
+        def _tail():
+            apply(idx_ref[whole:, :], val_ref[whole:, :])
+
+
+def _kernel(*refs, bits: int, plane_widths: Sequence[int], bn: int,
+            bk: int, k_out: int, tiles_per_launch: int, n_levels: int,
+            has_acc: bool, compute_dtype, x_mode: str, k_cols: int,
+            has_scale: bool):
+    if k_out > 0:
+        win_ref, x_ref, *rest, wt_ref = refs
+    else:
+        x_ref, *rest = refs
     nplanes = len(plane_widths)
     plane_refs = rest[:nplanes]
     rest = rest[nplanes:]
@@ -133,13 +175,18 @@ def _kernel(x_ref, *rest, bits: int, plane_widths: Sequence[int], bn: int,
 
     # --- outlier-reservation epilogue ---------------------------------------
     if k_out > 0:
-        n0 = pl.program_id(1) * bn
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0) + n0
-        idx = idx_ref[...]            # (k_out, bk) global row ids, -1 invalid
-        val = val_ref[...]            # (k_out, bk)
-        for r in range(k_out):
-            hit = idx[r:r + 1, :] == row_ids           # (bn, bk)
-            wt = jnp.where(hit, val[r:r + 1, :], wt)
+        # the window table is flat (plan tile, K block, lo/hi); this launch
+        # tile spans `tiles_per_launch` plan tiles, whose window is the lo
+        # of the first and the hi of the last (neither decreases down N)
+        j = pl.program_id(1)
+        n_kb = pl.num_programs(2)
+        first = j * tiles_per_launch
+        lo = win_ref[(first * n_kb + k_step) * 2]
+        hi = win_ref[((first + tiles_per_launch - 1) * n_kb + k_step) * 2 + 1]
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0) + j * bn
+        wt_ref[...] = wt
+        _outlier_epilogue(wt_ref, idx_ref, val_ref, lo, hi, row_ids, k_out)
+        wt = wt_ref[...]
 
     # --- MXU ------------------------------------------------------------------
     # f32 compute asks for f32 products outright rather than leaving the
@@ -172,9 +219,9 @@ launch_count = 0
     static_argnames=("bits", "n", "bm", "bn", "bk", "interpret",
                      "compute_dtype", "x_mode", "x_base", "k_cols"),
 )
-def _dequant_matmul(x, planes, codebook, out_idx, out_val, acc, x_scale, *,
-                    bits, n, bm, bn, bk, interpret, compute_dtype, x_mode,
-                    x_base, k_cols):
+def _dequant_matmul(x, planes, codebook, out_idx, out_val, out_win, acc,
+                    x_scale, *, bits, n, bm, bn, bk, interpret,
+                    compute_dtype, x_mode, x_base, k_cols):
     from repro.core import packing
 
     widths = packing.plane_widths(bits)
@@ -187,53 +234,73 @@ def _dequant_matmul(x, planes, codebook, out_idx, out_val, acc, x_scale, *,
     n_levels = 2 ** bits
 
     k_out = 0 if out_idx is None else out_idx.shape[0]
+    tiles_per_launch = 1
+    prefetch = []
+    if k_out > 0:
+        per_tile = grid[2] * 2          # (K block, lo/hi) entries a tile
+        if out_win is None:
+            # no plan: every launch tile visits every slot
+            out_win = jnp.tile(jnp.array(
+                [0, -(-k_out // SLOT_CHUNK) * SLOT_CHUNK], jnp.int32),
+                n // bn * grid[2])
+        assert out_win.ndim == 1 and out_win.shape[0] % (
+            n // bn * per_tile) == 0, (out_win.shape, n, bn, grid)
+        tiles_per_launch = out_win.shape[0] // (n // bn * per_tile)
+        prefetch = [out_win]
 
+    # index maps take the grid indices and then the scalar-prefetch refs
     if x_mode == "blocked":
         assert x.shape[1] == k_padded, (x.shape, k_padded)
-        in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))]
+        in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k))]
     elif x_mode == "aligned":
         # raw x; the group's fused K order IS original columns starting at
         # block offset x_base — a plain shifted block fetch, no indexing
         in_specs = [pl.BlockSpec((bm, bk),
-                                 lambda i, j, k, xb=x_base: (i, xb + k))]
+                                 lambda i, j, k, *_, xb=x_base: (i, xb + k))]
     else:
         raise ValueError(f"unknown x_mode {x_mode!r}")
     operands = [x]
     for w, p in zip(widths, planes):
         cpw = 32 // w
-        in_specs.append(pl.BlockSpec((bn // cpw, bk), lambda i, j, k: (j, k)))
+        in_specs.append(pl.BlockSpec((bn // cpw, bk),
+                                     lambda i, j, k, *_: (j, k)))
         operands.append(p)
-    in_specs.append(pl.BlockSpec((n_levels, bk), lambda i, j, k: (0, k)))
+    in_specs.append(pl.BlockSpec((n_levels, bk), lambda i, j, k, *_: (0, k)))
     operands.append(codebook)
     if k_out > 0:
-        in_specs.append(pl.BlockSpec((k_out, bk), lambda i, j, k: (0, k)))
-        in_specs.append(pl.BlockSpec((k_out, bk), lambda i, j, k: (0, k)))
+        in_specs.append(pl.BlockSpec((k_out, bk), lambda i, j, k, *_: (0, k)))
+        in_specs.append(pl.BlockSpec((k_out, bk), lambda i, j, k, *_: (0, k)))
         operands.extend([out_idx, out_val])
     if x_scale is not None:
         assert x_scale.shape == (m, 1), (x_scale.shape, m)
-        in_specs.append(pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)))
+        in_specs.append(pl.BlockSpec((bm, 1), lambda i, j, k, *_: (i, 0)))
         operands.append(x_scale)
     if acc is not None:
         assert acc.shape == (m, n), (acc.shape, m, n)
-        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)))
+        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)))
         operands.append(acc)
 
     kernel = functools.partial(
         _kernel, bits=bits, plane_widths=widths, bn=bn, bk=bk, k_out=k_out,
-        n_levels=n_levels, has_acc=acc is not None,
-        compute_dtype=compute_dtype, x_mode=x_mode, k_cols=k_cols,
-        has_scale=x_scale is not None)
+        tiles_per_launch=tiles_per_launch, n_levels=n_levels,
+        has_acc=acc is not None, compute_dtype=compute_dtype, x_mode=x_mode,
+        k_cols=k_cols, has_scale=x_scale is not None)
 
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
+            # the dequantized tile, kept in VMEM across the epilogue loop
+            scratch_shapes=([pltpu.VMEM((bn, bk), jnp.float32)]
+                            if k_out > 0 else [])),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, *operands)
 
 
 def dequant_matmul(
@@ -243,6 +310,8 @@ def dequant_matmul(
     out_idx: Optional[Array],     # (k_out, K) int32 global row ids, -1 pad
     out_val: Optional[Array],     # (k_out, K)
     *,
+    out_win: Optional[Array] = None,  # (n // bn * t * K // bk * 2,) int32
+    #                                   slot windows per tile of bn // t rows
     bits: int,
     n: int,                       # N = out features (rows of W)
     bm: int = DEFAULT_BM,
@@ -266,6 +335,12 @@ def dequant_matmul(
     per-token int8 activation scale into the output at the last K step
     (pass it on the LAST launch of a multi-group chain only).
 
+    `out_win` (kernels/plan.slot_windows) bounds the outlier slots each
+    (N tile, K block) visits; it needs each column's slots in ascending
+    row order, and its tiles may be any whole fraction bn // t of the
+    launch's.  Without it
+    every tile visits every slot, in any slot order.
+
     `interpret=True` runs the kernel through the Pallas interpreter, for
     hosts without a TPU; on a TPU it raises, so the chip only ever runs
     the compiled kernel."""
@@ -274,8 +349,8 @@ def dequant_matmul(
                          "interpret mode is for hosts without one")
     global launch_count
     launch_count += 1
-    return _dequant_matmul(x, tuple(planes), codebook, out_idx, out_val, acc,
-                           x_scale,
+    return _dequant_matmul(x, tuple(planes), codebook, out_idx, out_val,
+                           out_win, acc, x_scale,
                            bits=bits, n=n, bm=bm, bn=bn, bk=bk,
                            interpret=interpret, compute_dtype=compute_dtype,
                            x_mode=x_mode, x_base=x_base, k_cols=k_cols)

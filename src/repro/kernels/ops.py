@@ -177,7 +177,7 @@ def prepared_qmatmul(
             fetch = dict(x_mode="blocked")
         y = dm.dequant_matmul(
             xg, g.planes, g.codebook, g.out_idx, g.out_val,
-            bits=g.bits, n=pqt.n_padded, bm=bm,
+            out_win=g.out_win, bits=g.bits, n=pqt.n_padded, bm=bm,
             bn=launch_bn(pqt.bn, pqt.n_padded, g.bits), bk=g.bk,
             interpret=interpret, compute_dtype=compute_dtype, acc=y,
             x_scale=sp if gi == len(pqt.groups) - 1 else None, **fetch)

@@ -20,7 +20,13 @@ it once, at load/quantize time:
       emits an identity permutation), `x_start` is set and the kernel
       fetches raw x blocks with NO indexing at all (DESIGN.md §9);
   (c) outlier slots are pre-validated: the per-column count is converted
-      to idx=-1 padding once, instead of a mask per matmul;
+      to idx=-1 padding once, instead of a mask per matmul — and put in an
+      order the kernel can window: within each column the slots ascend by
+      row (padding last), and a gathered group's columns descend by
+      outlier count, so one K block holds columns of like counts.  Per
+      (bn-row tile, K block) the plan records the slot window `out_win`
+      outside which no slot of the block can hold a row of the tile; the
+      kernel's epilogue visits only that window (DESIGN.md §4);
   (d) stripes are grouped by bit-width and concatenated along K, so a
       matmul issues ONE fused `pallas_call` per distinct bit-width
       (typically 1-3) instead of one per stripe, with each group's output
@@ -91,8 +97,14 @@ class PlanGroup:
     """All same-bit-width stripes, concatenated along K and block-padded."""
     planes: Tuple[Array, ...]   # per plane-width: (n_padded//cpw, k_padded) u32
     codebook: Array             # (2**bits, k_padded) f32, zero at padding
-    out_idx: Optional[Array]    # (k_out, k_padded) int32, -1 = no outlier
+    out_idx: Optional[Array]    # (k_out, k_padded) int32, -1 = no outlier;
+    #                             each column's slots ascend by row
     out_val: Optional[Array]    # (k_out, k_padded) f32
+    out_win: Optional[Array]    # (n_tiles * (k_padded // bk) * 2,) int32:
+    #                             per (bn-row tile, K block) the slots
+    #                             [lo, hi), multiples of dm.SLOT_CHUNK, that
+    #                             can hold a row of the tile, flat
+    #                             (`slot_windows`)
     bits: int                   # static
     bk: int                     # static — K block size for this group
     k_cols: int                 # static — unpadded fused K of the group
@@ -118,7 +130,7 @@ class PlanGroup:
 
 jax.tree_util.register_dataclass(
     PlanGroup,
-    data_fields=["planes", "codebook", "out_idx", "out_val"],
+    data_fields=["planes", "codebook", "out_idx", "out_val", "out_win"],
     meta_fields=["bits", "bk", "k_cols", "x_start"])
 
 
@@ -227,6 +239,54 @@ def validated_outliers(qt: QuantizedTensor):
             jnp.where(valid, val_p, 0.0).astype(jnp.float32))
 
 
+def row_ordered(idx: Array, val: Array):
+    """Each column's outlier slots in ascending row order, padding (-1)
+    last.  Bitwise neutral: a column holds each row at most once, so the
+    order in which its slots override the dequantized tile is moot."""
+    key = jnp.where(idx >= 0, idx, jnp.iinfo(jnp.int32).max)
+    order = jnp.argsort(key, axis=0, stable=True)
+    return (jnp.take_along_axis(idx, order, axis=0),
+            jnp.take_along_axis(val, order, axis=0))
+
+
+def count_order(idx: Array, k_cols: int) -> Array:
+    """Fused K order for a gathered group: its `k_cols` columns by
+    descending outlier count (stable), the block-padding columns last.
+    A K block then holds columns of like counts, so one slot window fits
+    them all."""
+    count = jnp.sum(idx[:, :k_cols] >= 0, axis=0)
+    order = jnp.argsort(-count, stable=True).astype(jnp.int32)
+    return jnp.concatenate(
+        [order, jnp.arange(k_cols, idx.shape[1], dtype=jnp.int32)])
+
+
+def slot_windows(idx: Array, bn: int, n_tiles: int, bk: int) -> Array:
+    """Slot windows of row-ordered outliers `idx` (k_out, k_padded): a
+    flat int32 table indexed (tile t, K block b, lo/hi), flat so that it
+    is stored and handed to the kernel's scalar memory without padding
+    or relayout.  Over the columns of K block b that
+    hold any outlier, lo is the least count of a column's outliers on rows
+    < t*bn and hi the greatest on rows < (t+1)*bn, rounded outward to
+    whole `dm.SLOT_CHUNK`-slot chunks; [0, 0) where no column holds one.
+    Every slot holding a row of tile t lies in [lo, hi) (slots ascend by
+    row), and neither bound decreases down the tiles, so the window of a
+    run of tiles is the first's lo and the last's hi."""
+    k_out, k_padded = idx.shape
+    key = jnp.where(idx >= 0, idx, jnp.iinfo(jnp.int32).max)
+    bounds = jnp.arange(n_tiles + 1, dtype=jnp.int32) * bn
+    before = jax.vmap(lambda col: jnp.searchsorted(col, bounds))(key.T)
+    held = (idx[0] >= 0)[:, None]       # padding sorts last: slot 0 is
+    #                                     valid iff the column has any
+    blocks = (k_padded // bk, bk, n_tiles)
+    lo = jnp.min(jnp.where(held, before[:, :-1], k_out).reshape(blocks),
+                 axis=1)
+    hi = jnp.max(before[:, 1:].reshape(blocks), axis=1)
+    c = dm.SLOT_CHUNK
+    lo = jnp.minimum(lo, hi) // c * c
+    hi = (hi + c - 1) // c * c
+    return jnp.stack([lo.T, hi.T], axis=-1).astype(jnp.int32).reshape(-1)
+
+
 def _static_group_layout(stripes, bk: int):
     """Per-bit-width group layout derived from static stripe metadata only
     (bits + column counts) — identical for every member of a layer stack.
@@ -241,12 +301,12 @@ def _static_group_layout(stripes, bk: int):
         off += s.n_cols
     layout = []
     for bits in sorted({s.bits for s in stripes}):
-        members = [(o, si) for si, (o, s) in enumerate(zip(offsets, stripes))
-                   if s.bits == bits]
+        members = tuple((o, si) for si, (o, s)
+                        in enumerate(zip(offsets, stripes)) if s.bits == bits)
         k_cols = sum(stripes[si].n_cols for _, si in members)
         g_bk = min(bk, _round_up(k_cols, 128))
         layout.append((bits, members, k_cols, g_bk, _round_up(k_cols, g_bk)))
-    return layout
+    return tuple(layout)
 
 
 def _aligned_x_starts(qt: QuantizedTensor, layout):
@@ -303,13 +363,22 @@ def prepare_for_inference(
     (x_start is static meta, so all members must agree on it).
     """
     layout = _static_group_layout(qt.stripes, bk)
-    x_starts = _aligned_x_starts(qt, layout)
+    x_starts = tuple(_aligned_x_starts(qt, layout))
+    stack_dims = qt.stripes[0].packed.ndim - 2
+    return _plan_builder(bn, layout, x_starts, stack_dims)(qt)
+
+
+@functools.cache
+def _plan_builder(bn: int, layout, x_starts, stack_dims: int):
+    """`_build_plan` vmapped over the stack dims and jitted: one program
+    per plan layout, shared by a tree's leaves of that layout until
+    `prepare_tree` drops it (the persistent compile cache keeps it), in
+    place of a compile for each of its many small ops."""
     build = functools.partial(_build_plan, bn=bn, layout=layout,
                               x_starts=x_starts)
-    stack_dims = qt.stripes[0].packed.ndim - 2
     for _ in range(stack_dims):
         build = jax.vmap(build)
-    return build(qt)
+    return jax.jit(build)
 
 
 def _build_plan(qt: QuantizedTensor, *, bn: int, layout,
@@ -341,7 +410,16 @@ def _build_plan(qt: QuantizedTensor, *, bn: int, layout,
             if len(members) > 1 else qt.stripes[members[0][1]].codebook
         cb = jnp.pad(cb.astype(jnp.float32).T, ((0, 0), (0, k_padded - k_cols)))
 
-        g_oi = g_ov = None
+        idx = jnp.concatenate(
+            [jax.lax.slice_in_dim(qt.col_perm, o, o + qt.stripes[si].n_cols)
+             for o, si in members]) if len(members) > 1 \
+            else jax.lax.slice_in_dim(
+                qt.col_perm, members[0][0],
+                members[0][0] + qt.stripes[members[0][1]].n_cols)
+        idx = jnp.pad(idx.astype(jnp.int32), (0, k_padded - k_cols),
+                      constant_values=qt.cols)
+
+        g_oi = g_ov = win = None
         if oi is not None:
             g_oi = jnp.concatenate(
                 [jax.lax.slice_in_dim(oi, o, o + qt.stripes[si].n_cols,
@@ -354,20 +432,22 @@ def _build_plan(qt: QuantizedTensor, *, bn: int, layout,
             g_oi = jnp.pad(g_oi, ((0, 0), (0, k_padded - k_cols)),
                            constant_values=-1)
             g_ov = jnp.pad(g_ov, ((0, 0), (0, k_padded - k_cols)))
-
-        idx = jnp.concatenate(
-            [jax.lax.slice_in_dim(qt.col_perm, o, o + qt.stripes[si].n_cols)
-             for o, si in members]) if len(members) > 1 \
-            else jax.lax.slice_in_dim(
-                qt.col_perm, members[0][0],
-                members[0][0] + qt.stripes[members[0][1]].n_cols)
-        idx = jnp.pad(idx.astype(jnp.int32), (0, k_padded - k_cols),
-                      constant_values=qt.cols)
+            g_oi, g_ov = row_ordered(g_oi, g_ov)
+            if x_start is None:
+                # a gathered group's K order is free: one permutation of
+                # every K-indexed array, the gather index included
+                order = count_order(g_oi, k_cols)
+                planes = [jnp.take(p, order, axis=1) for p in planes]
+                cb, g_oi, g_ov = (jnp.take(a, order, axis=1)
+                                  for a in (cb, g_oi, g_ov))
+                idx = jnp.take(idx, order)
+            win = slot_windows(g_oi, bn, n_padded // bn, g_bk)
         idx_parts.append(idx)
 
         groups.append(PlanGroup(
             planes=tuple(planes), codebook=cb, out_idx=g_oi, out_val=g_ov,
-            bits=bits, bk=g_bk, k_cols=k_cols, x_start=x_start))
+            out_win=win, bits=bits, bk=g_bk, k_cols=k_cols,
+            x_start=x_start))
 
     return PreparedQuantizedTensor(
         groups=tuple(groups),
@@ -376,15 +456,47 @@ def _build_plan(qt: QuantizedTensor, *, bn: int, layout,
         shape=qt.shape, n_padded=n_padded, bn=bn)
 
 
+def or_slot_share(params) -> Optional[float]:
+    """Share of the outlier slots the kernel's epilogue visits over every
+    prepared leaf of `params`, in %: per launch tile and K block, the
+    window's width (capped at k_out) times the tile's elements, summed,
+    over k_out times the same elements.  None where no leaf has outliers.
+    Reads the small window tables to the host."""
+    visited = every = 0
+    for leaf in jax.tree_util.tree_leaves(
+            params, is_leaf=lambda l: isinstance(l, PreparedQuantizedTensor)):
+        if not isinstance(leaf, PreparedQuantizedTensor):
+            continue
+        for g in leaf.groups:
+            if g.out_win is None:
+                continue
+            k_out = g.out_idx.shape[-2]
+            tile = launch_bn(leaf.bn, leaf.n_padded, g.bits)
+            d = tile // leaf.bn
+            win = np.asarray(g.out_win).reshape(
+                g.out_win.shape[:-1] + (leaf.n_tiles, g.k_padded // g.bk, 2))
+            lo, hi = win[..., ::d, :, 0], win[..., d - 1::d, :, 1]
+            width = np.clip(np.minimum(hi, k_out) - lo, 0, None)
+            visited += int(width.sum()) * tile * g.bk
+            every += k_out * width.size * tile * g.bk
+    return 100.0 * visited / every if every else None
+
+
 def prepare_tree(params, *, bn: int = dm.DEFAULT_BN, bk: int = dm.DEFAULT_BK):
     """Replace every QuantizedTensor leaf in a params tree with its prepared
-    form (identity on dense leaves).  Engines call this once at load."""
+    form (identity on dense leaves).  Engines call this once at load.
+
+    The plan builders are dropped once the tree is built: a loaded program
+    keeps its code in device memory (the sorts make each 5-16 MB on a TPU,
+    ~54 MB at glm4_9b widths), and a tree is prepared once."""
     def one(leaf):
         if isinstance(leaf, QuantizedTensor):
             return leaf.prepare(bn=bn, bk=bk)
         return leaf
 
-    return jax.tree_util.tree_map(
+    prepared = jax.tree_util.tree_map(
         one, params,
         is_leaf=lambda l: isinstance(l, (QuantizedTensor,
                                          PreparedQuantizedTensor)))
+    _plan_builder.cache_clear()
+    return prepared

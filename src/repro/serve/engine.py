@@ -156,7 +156,7 @@ from repro.analysis.trace_rules import TraceSentinel
 from repro.dist import context as dctx
 from repro.dist import sharding as shd
 from repro.kernels import ops as kops
-from repro.kernels.plan import prepare_tree
+from repro.kernels.plan import or_slot_share, prepare_tree
 from repro.models import api
 from repro.models import modules as nn
 
@@ -424,6 +424,10 @@ class ServingEngine:
         if plan_bk is not None:
             prep_kw["bk"] = plan_bk
         self.params = prepare_tree(params, **prep_kw) if prepare else params
+        if telemetry is not None:
+            share = or_slot_share(self.params)
+            if share is not None:
+                telemetry.registry.gauge("kernel.or_slot_share").set(share)
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len

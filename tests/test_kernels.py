@@ -1,5 +1,7 @@
 """Pallas dequant-GEMM kernel vs the pure-jnp oracle (interpret mode):
 shape/dtype/bit-width sweeps, outlier epilogue, multi-stripe AP tensors."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -173,3 +175,129 @@ def test_block_shape_sweep():
                               bm=bm, bn=bn, bk=bk)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-3)
+
+
+# --------------------------------------------- Outlier-Reservation windows
+
+def _boundary_qt(rng, rows, bn, stripe_spec, k_out, aligned=False):
+    """A QuantizedTensor whose outliers sit on the first and last row of
+    the bn-row tiles and on random rows, in shuffled slot order, with a
+    quarter of the columns holding none and stale row ids past each
+    column's count.  `aligned`: identity column order (one bit-width)."""
+    from repro.core.quantized import QuantStripe, QuantizedTensor
+    cols = sum(n for _, n in stripe_spec)
+    stripes = []
+    for bits, n_cols in stripe_spec:
+        codes = rng.integers(0, 2 ** bits, size=(rows, n_cols))
+        cb = np.sort(rng.normal(size=(n_cols, 2 ** bits)), axis=1)
+        stripes.append(QuantStripe(
+            packed=packing.pack_codes(jnp.asarray(codes, jnp.int32), bits),
+            codebook=jnp.asarray(cb, jnp.float32), bits=bits))
+    edges = np.unique([r for t in range(0, rows, bn) for r in (t, t + bn - 1)
+                       if r < rows])
+    oi = np.empty((k_out, cols), np.int32)
+    for c in range(cols):
+        first = rng.permutation(edges)[:k_out // 2]
+        rest = rng.permutation(np.setdiff1d(np.arange(rows), first))
+        oi[:, c] = rng.permutation(
+            np.concatenate([first, rest[:k_out - len(first)]]))
+    cnt = rng.integers(1, k_out + 1, size=cols)
+    cnt[rng.random(cols) < 0.25] = 0
+    perm = np.arange(cols) if aligned else rng.permutation(cols)
+    return QuantizedTensor(
+        stripes=tuple(stripes), col_perm=jnp.asarray(perm, jnp.int32),
+        out_idx=jnp.asarray(oi),
+        out_val=jnp.asarray(rng.normal(size=(k_out, cols)), jnp.float32),
+        out_count=jnp.asarray(cnt, jnp.int32), shape=(rows, cols))
+
+
+def _full_window(pqt):
+    """The same plan with no window table: every tile visits every slot."""
+    import dataclasses
+    return dataclasses.replace(pqt, groups=tuple(
+        dataclasses.replace(g, out_win=None) for g in pqt.groups))
+
+
+# (id, rows, plan bn, stripes, outliers/col, aligned): one aligned group;
+# gathered groups with whole chunks and a tail, one launch tile over
+# three plan tiles; 3-bit launch tiles of two plan tiles
+WINDOW_LAYOUTS = [
+    ("aligned-2bit", 200, 128, [(2, 256)], 12, True),
+    ("ap-gathered-bn32", 96, 32, [(2, 160), (4, 96)], 12, False),
+    ("ap-3bit-whole-chunks", 300, 128, [(3, 100), (2, 60)], 16, False),
+]
+
+
+@pytest.mark.parametrize("layout", WINDOW_LAYOUTS,
+                         ids=[w[0] for w in WINDOW_LAYOUTS])
+def test_windowed_epilogue_parity(layout):
+    """Outliers on tile-boundary rows, columns with none, count-ordered
+    gathered groups: the windowed kernel matches the reference, and is
+    bitwise the kernel that visits every slot of the same plan."""
+    name, rows, bn, spec, k_out, aligned = layout
+    rng = np.random.default_rng(len(name))
+    qt = _boundary_qt(rng, rows, bn, spec, k_out, aligned)
+    pqt = qt.prepare(bn=bn)
+    assert pqt.x_gather_free == aligned
+    x = jnp.asarray(rng.normal(size=(9, qt.cols)).astype(np.float32))
+    y = ops.prepared_qmatmul(x, pqt)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(ref_lib.ref_qmatmul(x, qt)),
+                               rtol=1e-4, atol=1e-3)
+    full = ops.prepared_qmatmul(x, _full_window(pqt))
+    assert np.array_equal(np.asarray(y), np.asarray(full))
+
+
+def test_windowed_epilogue_parity_layer_stacked():
+    """A layer-stacked plan (prepared under vmap): each layer's slice
+    runs its own windows, bitwise the full-window kernel and within
+    tolerance of that layer's reference."""
+    rng = np.random.default_rng(11)
+    spec = [(2, 160), (4, 96)]
+    layers = [_boundary_qt(rng, 96, 32, spec, 12) for _ in range(2)]
+    stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *layers)
+    pqt = stacked.prepare(bn=32)
+    x = jnp.asarray(rng.normal(size=(5, layers[0].cols)).astype(np.float32))
+    for i, qt in enumerate(layers):
+        one = jax.tree_util.tree_map(lambda a: a[i], pqt)
+        y = ops.prepared_qmatmul(x, one)
+        np.testing.assert_allclose(np.asarray(y),
+                                   np.asarray(ref_lib.ref_qmatmul(x, qt)),
+                                   rtol=1e-4, atol=1e-3)
+        full = ops.prepared_qmatmul(x, _full_window(one))
+        assert np.array_equal(np.asarray(y), np.asarray(full))
+
+
+def test_windowed_epilogue_parity_n_sharded(subproc):
+    """An N-sharded plan (dist.sharding.sharded_qmatmul on a 2x2 mesh):
+    each model shard takes the window rows of its own tiles, whose launch
+    tile there spans both of its plan tiles; with outliers on every
+    tile-boundary row it is bitwise the single-device windowed kernel."""
+    subproc('''
+import inspect, sys
+import jax, jax.numpy as jnp, numpy as np
+sys.path.insert(0, %r)
+from test_kernels import _boundary_qt, _full_window
+from repro.dist.sharding import MeshAxes, sharded_qmatmul, spec_for_quantized
+from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+from repro.models import modules as nn
+
+rng = np.random.default_rng(5)
+qt = _boundary_qt(rng, 128, 32, [(2, 160), (4, 96)], 12)
+pqt = qt.prepare(bn=32)                 # 4 tiles: 2 per model shard
+mesh = make_mesh((2, 2), ("data", "model"))
+assert pqt.shards_whole_tiles(2)
+specs = spec_for_quantized(pqt, MeshAxes(mesh))
+assert all(s.out_win[-1] == "model" for s in specs.groups)
+x = jnp.asarray(rng.normal(size=(4, qt.cols)).astype(np.float32))
+with nn.quant_mode("kernel"):
+    one = jax.jit(ops.prepared_qmatmul)(x, pqt)
+    full = jax.jit(ops.prepared_qmatmul)(x, _full_window(pqt))
+    with mesh:
+        got = jax.jit(lambda a, p: sharded_qmatmul(a, p, mesh))(x, pqt)
+assert np.array_equal(np.asarray(one), np.asarray(full))
+assert np.array_equal(np.asarray(got), np.asarray(one)), float(
+    jnp.max(jnp.abs(got - one)))
+print("sharded windows bitwise OK")
+''' % os.path.dirname(os.path.abspath(__file__)), devices=4, timeout=600)

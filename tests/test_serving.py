@@ -356,3 +356,36 @@ def test_run_to_completion_surfaces_truncation(fp_model):
     unfinished = eng.run_to_completion(max_steps=2, strict=False)
     assert unfinished == [uid]
     assert eng.run_to_completion() == []  # now finishes; [] == complete
+
+
+def test_engine_records_the_outlier_slot_share(fp_model, quantized_model):
+    """At construction an engine with a recorder records the share of
+    outlier slots its prepared weights' kernel epilogues visit
+    (`kernel.or_slot_share`, DESIGN.md §13) — what the benchmark's reader
+    of that name reads; a model without outliers records none."""
+    import importlib.util
+    import pathlib
+    import types
+
+    from repro.kernels.plan import or_slot_share
+    from repro.serve import Telemetry
+
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "metrics" / "kernel.or_slot_share.py")
+    spec = importlib.util.spec_from_file_location("or_slot_share_reader",
+                                                  path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    cfg, qparams = quantized_model
+    tel = Telemetry()
+    eng = ServingEngine(qparams, cfg, n_slots=2, max_len=32, telemetry=tel)
+    share = tel.registry.get("kernel.or_slot_share").value
+    assert 0 < share <= 100 and share == or_slot_share(eng.params)
+    assert reader.read(types.SimpleNamespace(telemetry=tel)) == share
+
+    cfg, params = fp_model
+    tel = Telemetry()
+    ServingEngine(params, cfg, n_slots=2, max_len=32, telemetry=tel)
+    assert tel.registry.get("kernel.or_slot_share") is None
+    assert reader.read(types.SimpleNamespace(telemetry=tel)) is None

@@ -84,6 +84,8 @@ CASES = [  # (id, shape, column bit-widths, outliers/col, x dtype, act_dtype,
     ("4bit-int8-act", DOWN, (4,), 0, jnp.float32, "int8", 128),
     ("3bit-or-bf16", GATE_UP, (3,), 4, jnp.bfloat16, None, 128),
     ("ap2.5-or-bf16", DOWN, (2, 4), 4, jnp.bfloat16, None, 128),
+    # OR windows past one 8-slot chunk: the chunk loop and the tail
+    ("ap2.5-or12-bf16", GATE_UP, (2, 4), 12, jnp.bfloat16, None, 128),
     # user-set plan tiles below a legal block: launches coarsen them
     ("2bit-bn32", GATE_UP, (2,), 0, jnp.float32, None, 32),
     ("ap2.5-or-bn32", GATE_UP, (2, 3), 2, jnp.bfloat16, None, 32),
