@@ -2,8 +2,13 @@
 
 A workload names a configuration and a traffic mix; each is a data file
 found by name (`bench/configs/<config>.json`, `bench/traffic/<mix>.json`),
-and each per-layer metric a reader `bench/metrics/<metric>.py`.  Adding a
-cell, a mix or a metric therefore adds files and an entry, and edits none.
+and each per-layer metric a reader `bench/metrics/<metric>.py`; a model
+family's weight builder (`bench/params/<family>.py`) and plain reference
+(`bench/reference/<family>.py`) are found by the family's name.  A
+configuration file that states `"mesh": {"data": d, "model": t}` is
+served on a mesh of d x t chips, which has to be the cell's `chips`.
+Adding a cell, a mix, a metric or a family therefore adds files and
+entries, and edits none.
 """
 from __future__ import annotations
 
@@ -11,7 +16,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -23,8 +28,12 @@ MODEL_FIELDS = {
     "head_dim": "head_dim", "intermediate_size": "d_ff",
     "vocab_size": "vocab", "attention_bias": "qkv_bias",
     "tie_word_embeddings": "tie_embeddings", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "family": "family",
+    "rms_norm_eps": "norm_eps", "family": "family", "qk_norm": "qk_norm",
 }
+# keys a configuration file may leave out, and what leaving them out means
+OPTIONAL = {"qk_norm": False}
+# the mesh axes a configuration file's `mesh` names, major to minor
+MESH_AXES = ("data", "model")
 # ModelConfig fields a configuration file may set apart from the registry
 NUMERICS = ("norm_eps",)
 
@@ -57,6 +66,11 @@ def load_cell(name: str, root: pathlib.Path = ROOT,
     w = work[name]
     conf_entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
     conf = json.loads((root / conf_entry["file"]).read_text())
+    data, model = mesh_shape(conf)
+    if data * model != w["chips"]:
+        raise ValueError(f"{name}: a mesh of {data} x {model} chips "
+                         f"(configuration {w['config']!r}) on a cell of "
+                         f"{w['chips']} chip(s)")
     traffic = json.loads((bench / "traffic" / f"{w['traffic']}.json")
                          .read_text())
     return Cell(name=name, conf=conf, traffic=traffic, chips=w["chips"],
@@ -64,6 +78,18 @@ def load_cell(name: str, root: pathlib.Path = ROOT,
                             if _applies(m, name)],
                 per_layer=[m for m in spec["per_layer"]
                            if _applies(m, name)])
+
+
+def mesh_shape(conf: Dict) -> Tuple[int, int]:
+    """(data, model) sizes of the configuration's mesh; (1, 1), one chip
+    and no mesh, where the file states none."""
+    mesh = conf.get("mesh")
+    if mesh is None:
+        return 1, 1
+    if set(mesh) != set(MESH_AXES):
+        raise ValueError(f"a mesh names the axes {MESH_AXES}, not "
+                         f"{sorted(mesh)}")
+    return mesh["data"], mesh["model"]
 
 
 def load_module(path: pathlib.Path, name: str):
@@ -84,6 +110,11 @@ def metric_reader(name: str, bench: pathlib.Path = BENCH):
                        "metric_" + name.replace(".", "_").replace("-", "_"))
 
 
+def weights_for(family: str, bench: pathlib.Path = BENCH):
+    """The family's weight builder: `served_params(seed, conf, mesh)`."""
+    return load_module(bench / "params" / f"{family}.py", f"params_{family}")
+
+
 def reference_for(family: str, bench: pathlib.Path = BENCH):
     return load_module(bench / "reference" / f"{family}.py",
                        f"reference_{family}")
@@ -98,7 +129,8 @@ def model_config(conf: Dict, strict: bool = True):
     from repro.configs import get_config
 
     cfg = get_config(conf["arch"])
-    changes = {MODEL_FIELDS[k]: conf[k] for k in MODEL_FIELDS}
+    given = {**OPTIONAL, **conf}
+    changes = {MODEL_FIELDS[k]: given[k] for k in MODEL_FIELDS}
     rot = conf["rotary_dim"]
     changes["rotary_dim"] = 0 if rot == conf["head_dim"] else rot
     changes["dtype"] = conf["dtype"]

@@ -7,7 +7,10 @@ levels a column), its share of the reserved outliers (an int32 row and an
 f32 value each), the activation rows it contracts (bf16) and its f32
 output, each once; and 2*M*N*K operations.  Padding, tile re-reads and the
 VPU work of dequantization are not counted: they are what a faster kernel
-may remove.
+may remove.  On a (data, model) mesh every chip makes its own calls: the
+matrix's rows split over `model`, the activation rows over `data`, each
+chip reading its share of the planes, outliers and output, the whole
+codebook and its activation rows; the calls of every chip are counted.
 
 Model side: operations per token are 2 x the matmul parameters (the head
 included) plus attention over the live context (QK^T and PV, 4 x layers x
@@ -17,6 +20,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Tuple
 
+from bench import cell
 from bench import weights as W
 
 ACT_BYTES = 2      # bf16 activations
@@ -27,20 +31,25 @@ OUTLIER_BYTES = 8  # int32 row + f32 value
 
 def kernel_calls(conf: Dict, m: int) -> List[Tuple[float, float]]:
     """(operations, bytes) of every kernel call in one forward pass over
-    `m` activation rows (the rows the call is given, padding included)."""
+    `m` activation rows (the rows the call is given, padding included),
+    on every chip of the configuration's mesh."""
+    data, model = cell.mesh_shape(conf)
+    m_chip = m / data
     out = []
     for rows, cols in W.matrix_shapes(conf).values():
         lay = W.layout(rows, cols, conf["claq"])
         outliers = lay.n_top * lay.k_top + (cols - lay.n_top) * lay.k_rest
+        rows_chip = rows / model
         for bits, k in ((lay.p_lo, cols - lay.n_hi), (lay.p_hi, lay.n_hi)):
             if k == 0:
                 continue
-            ops = 2.0 * m * rows * k
-            nbytes = (rows * k * bits / 8 + (1 << bits) * k * CB_BYTES
-                      + outliers * k / cols * OUTLIER_BYTES
-                      + m * k * ACT_BYTES + m * rows * OUT_BYTES)
+            ops = 2.0 * m_chip * rows_chip * k
+            nbytes = (rows_chip * k * bits / 8 + (1 << bits) * k * CB_BYTES
+                      + outliers * k / cols / model * OUTLIER_BYTES
+                      + m_chip * k * ACT_BYTES
+                      + m_chip * rows_chip * OUT_BYTES)
             out.append((ops, nbytes))
-    return out * conf["num_hidden_layers"]
+    return out * (conf["num_hidden_layers"] * data * model)
 
 
 def least_time_s(calls, peaks: Dict) -> Tuple[float, float, float]:

@@ -2,7 +2,8 @@
 executions: the least time of its calls at the chip's peaks (per call the
 larger of operations / peak FLOP/s and bytes / peak HBM bandwidth, from
 the plan's shapes, bench/flops.py) over the summed device time of its
-operations, in percent."""
+operations, in percent.  On a mesh both sides are summed over the chips:
+each chip's calls, and each chip's kernel time."""
 from bench import flops
 
 

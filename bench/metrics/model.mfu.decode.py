@@ -2,8 +2,9 @@
 the decode steps produced (2 x matmul parameters + attention over each
 slot's live context, bench/flops.py) over the wall period of those steps
 (from each decode execution's start to the next step program's start,
-idle gaps included) times the chip's peak, in percent."""
-from bench import flops, trace_reduce
+idle gaps included) times the peak of all the chips of the configuration's
+mesh, in percent."""
+from bench import cell, flops, trace_reduce
 
 
 def read(ctx):
@@ -18,4 +19,5 @@ def read(ctx):
                                   ctx.trace_end)
     if n == 0 or period <= 0:
         return None
-    return 100.0 * ops / (period * ctx.peaks["bf16_flops"])
+    data, model = cell.mesh_shape(ctx.conf)
+    return 100.0 * ops / (period * ctx.peaks["bf16_flops"] * data * model)

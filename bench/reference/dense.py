@@ -7,9 +7,11 @@ benchmark's own draws (`bench.weights.raw_*`), dequantized here from codes,
 codebooks and reserved outliers; nothing is taken from the program.
 
 The block follows the dense family as the served configurations state it:
-RMSNorm, GQA attention with QKV bias and RoPE over the first `rotary_dim`
-dims of each head (dims paired as (2i, 2i+1), the program's convention),
-SwiGLU MLP, residuals, a final RMSNorm and an untied or tied head.
+RMSNorm, GQA attention with QKV bias, an RMSNorm of each q and k head
+where the file states `qk_norm` (Qwen3), and RoPE over the first
+`rotary_dim` dims of each head (dims paired as (2i, 2i+1), the program's
+convention), SwiGLU MLP, residuals, a final RMSNorm and an untied or tied
+head.
 The layers run one at a time, each drawing its weights afresh, so that the
 reference fits beside nothing else on the chip.
 """
@@ -75,6 +77,9 @@ def _block(h, mats, leaves, mc_items):
     q = lin(x, "attn.q").reshape(N, S, H, hd)
     k = lin(x, "attn.k").reshape(N, S, KH, hd)
     v = lin(x, "attn.v").reshape(N, S, KH, hd)
+    if mc.get("qk_norm"):
+        q = _rms(q, leaves["attn.q_norm.scale"], eps)
+        k = _rms(k, leaves["attn.k_norm.scale"], eps)
     rot = mc["rotary_dim"] or hd
     q, k = _rope(q, rot, mc["rope_theta"]), _rope(k, rot, mc["rope_theta"])
     h = h + lin(_attention(q, k, v).reshape(N, S, H * hd), "attn.o")
@@ -108,7 +113,7 @@ def logits(conf: Dict, seed: int, tokens, positions):
     keeps padding out of every earlier position); positions (N, P) int32 —
     where to read the next-token logits.  Returns (N, P, V) float32.
     """
-    mc = {k: conf[k] for k in _MC_KEYS}
+    mc = {k: conf[k] for k in _MC_KEYS if k in conf}
     key = W.base_key(seed)
     L = mc["num_hidden_layers"]
     specs = W.dense_leaf_specs(mc, jnp.bfloat16)

@@ -63,7 +63,7 @@ def main(argv=None) -> int:
     one = SingleDeviceSharding(topo.devices[0])
     dm._platform = lambda: "tpu"          # trace the compiled-kernel path
 
-    mc = tuple((k, conf[k]) for k in weights.MODEL_KEYS)
+    mc = weights.model_items(conf)
     claq = tuple(sorted(conf["claq"].items()))
     params = jax.eval_shape(
         lambda k: prepare_tree(weights._build(k, mc, claq)),

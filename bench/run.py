@@ -2,16 +2,17 @@
 
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-One process holds the chip.  It builds the cell's seeded CLAQ weights on
-the device, starts the program's `ServingEngine` on them, warms up every
-prefill and decode shape the cell's traffic uses, fills the slots, then
-serves the traffic for `--seconds` and prints one JSON line last on
-stdout: `correct`, `attempted`, `failed`, `metrics`, `device` (and with
-`--trace 1` a `breakdown`), and last the numbers compared with their
-limits under `checks`.  With `--trace 0` the metrics are the cell's
-end-to-end metrics, with `--trace 1` its per-layer metrics.  It exits
-non-zero, printing no result, when JAX finds no TPU or fewer chips than
-the cell asks for.
+One process holds the chips.  It builds the cell's seeded CLAQ weights on
+the device (on a mesh where the configuration file states one, each chip
+building its share), starts the program's `ServingEngine` on them (with
+that mesh), warms up every prefill and decode shape the cell's traffic
+uses, fills the slots, then serves the traffic for `--seconds` and prints
+one JSON line last on stdout: `correct`, `attempted`, `failed`,
+`metrics`, `device` (and with `--trace 1` a `breakdown`), and last the
+numbers compared with their limits under `checks`.  With `--trace 0` the
+metrics are the cell's end-to-end metrics, with `--trace 1` its per-layer
+metrics.  It exits non-zero, printing no result, when JAX finds no TPU or
+fewer chips than the cell asks for.
 """
 from __future__ import annotations
 
@@ -24,6 +25,7 @@ import gc  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 import types  # noqa: E402
 
@@ -33,11 +35,12 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import numpy as np  # noqa: E402
 
 from bench import cell as cells  # noqa: E402
-from bench import check, e2e, serve_loop  # noqa: E402
+from bench import check, e2e, engine_spans, serve_loop  # noqa: E402
 
 BENCH = ROOT / "bench"
 OUT = ROOT / ".bench_out"        # traces; inside the checkout, git-ignored
 DRAIN_SECONDS = 60.0             # wait past the close for first tokens
+SLOW_STEP = 5.0                  # a stall: a step over this x the median
 
 
 def log(**kv) -> None:
@@ -93,21 +96,64 @@ def count_compiles():
     return times
 
 
-def start_engine(cell, cfg, seed: int, act_dtype=None):
+def mesh_for(conf, devs):
+    """The configuration's (data, model) mesh over `devs`, the chips
+    `chips_or_exit` gave the cell, with every axis Auto as the program's
+    launcher builds it (`launch.mesh.make_mesh`); None without."""
+    if "mesh" not in conf:
+        return None
+    import jax
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(cells.mesh_shape(conf), cells.MESH_AXES,
+                         devices=devs,
+                         axis_types=(AxisType.Auto,) * len(cells.MESH_AXES))
+
+
+def start_engine(cell, cfg, seed: int, devs, act_dtype=None):
     """The program's engine on the cell's seeded weights, as the cell
     serves them: bf16 slot cache, the contiguous layout, the compiled
-    kernel the platform picks, no speculation, paging or controller."""
+    kernel the platform picks, the configuration's mesh over `devs`, no
+    speculation, paging or controller."""
     import jax.numpy as jnp
 
     from repro.serve import ServingEngine, Telemetry
 
-    from bench import weights
+    conf, mix = cell.conf, cell.traffic
+    mesh = mesh_for(conf, devs)
+    params = cells.weights_for(conf["family"], BENCH).served_params(
+        seed, conf, mesh)
+    return ServingEngine(params, cfg, n_slots=mix["n_slots"],
+                         max_len=mix["max_len"], dtype=jnp.bfloat16,
+                         act_dtype=act_dtype, mesh=mesh,
+                         telemetry=Telemetry(), **conf["engine"])
 
-    mix = cell.traffic
-    return ServingEngine(weights.served_params(seed, cell.conf), cfg,
-                         n_slots=mix["n_slots"], max_len=mix["max_len"],
-                         dtype=jnp.bfloat16, act_dtype=act_dtype,
-                         telemetry=Telemetry(), **cell.conf["engine"])
+
+def stalls(tel, t_open: float, t_close: float) -> dict:
+    """The longest `engine.step` inside the window and the phase that took
+    most of it; and, over the steps that admitted nothing (decode alone,
+    whose time is steady), the longest and how many took over SLOW_STEP x
+    their median.  A stall shows here before it shows as spread in
+    `tokens_per_s`; a refill's prefill is no stall."""
+    steps = [s for s in engine_spans.steps(tel)
+             if t_open <= s["start"] and s["end"] <= t_close]
+    if not steps:
+        return {"max_step_ms": "na"}
+    slow = max(steps, key=engine_spans.wall)
+    kids = engine_spans.children(tel).get(slow["id"], {})
+    phase = max(kids.values(), key=engine_spans.wall)["name"] if kids \
+        else "none"
+    out = {"max_step_ms": f"{1e3 * engine_spans.wall(slow):.3f}",
+           "max_step_phase": phase}
+    walls = [engine_spans.wall(s) for s in steps
+             if not s["attrs"].get("admitted")]
+    if walls:
+        median = statistics.median(walls)
+        out.update(max_decode_step_ms=f"{1e3 * max(walls):.3f}",
+                   median_decode_step_ms=f"{1e3 * median:.3f}",
+                   slow_decode_steps=sum(w > SLOW_STEP * median
+                                         for w in walls))
+    return out
 
 
 def warm_up(eng, requests, batches, max_tokens) -> int:
@@ -129,7 +175,25 @@ def warm_up(eng, requests, batches, max_tokens) -> int:
     eng.add_requests([[1] * (buckets[0] - 1)], max_new_tokens=2)
     eng.step()
     eng.take_finished()
+    if eng.mesh is not None:
+        calls += warm_mesh_layouts(eng, buckets[0], max(batches))
     return calls
+
+
+def warm_mesh_layouts(eng, bucket: int, max_rows: int) -> int:
+    """On a mesh a step's programs depend on the slot cache's layout too:
+    an insert pins it, a decode leaves it as the compiler chose.  Run a
+    decode after a decode, and an insert of each group size after a
+    decode, each a program of its own there.  One chip has one layout."""
+    eng.add_requests([[1] * (bucket - 1)], max_new_tokens=max_rows + 4)
+    eng.step()
+    eng.step()
+    for n in range(1, max_rows + 1):
+        eng.add_requests([[1] * (bucket - 1)] * n, max_new_tokens=1)
+        eng.step()
+    eng.run_to_completion()
+    eng.take_finished()
+    return max_rows + 1
 
 
 def trace_window(driver, t0, t_open, t_close, seconds):
@@ -197,7 +261,7 @@ def measure(args, cell, devs, compiles, t_start: float,
     requests = gen.generate(mix, args.seed, args.seconds, conf["vocab_size"])
 
     t_engine = serve_loop.clock()
-    eng = start_engine(cell, cfg, args.seed, act_dtype)
+    eng = start_engine(cell, cfg, args.seed, devs, act_dtype)
     t_warm = serve_loop.clock()
     warm_calls = warm_up(eng, requests, mix["warm_batches"],
                          mix["warm_max_tokens"])
@@ -248,6 +312,7 @@ def measure(args, cell, devs, compiles, t_start: float,
             "engine_steps"], window_steps=driver.steps,
         preemptions=stats["preemptions"], queued_at_end=stats["queued"],
         rejected=stats["admission_rejections"])
+    log(**stalls(eng.telemetry, t_open, t_close))
     log(generator_late_ms_p50=f"{np.percentile(late, 50):.3f}"
         if late else "na",
         generator_late_ms_max=f"{max(late):.3f}" if late else "na",

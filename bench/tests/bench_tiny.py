@@ -34,8 +34,11 @@ BATCH = {"kind": "closed_loop", "n_slots": 4, "max_len": 128, "pool": 64,
 
 
 def tiny_root(tmp: pathlib.Path, mix=CHAT, limit: float = 0.5,
-              workload: str = "tiny.chat") -> pathlib.Path:
-    """`limit` is on the mean logit gap, the number the cells compare."""
+              workload: str = "tiny.chat", conf_extra=None,
+              chips: int = 1) -> pathlib.Path:
+    """`limit` is on the mean logit gap, the number the cells compare;
+    `conf_extra` is put into the tiny configuration file (a `mesh`,
+    `qk_norm`, another depth)."""
     root = tmp / "root"
     shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
@@ -43,13 +46,14 @@ def tiny_root(tmp: pathlib.Path, mix=CHAT, limit: float = 0.5,
     conf = json.loads((ROOT / "bench/configs/qwen2_1p5b.json").read_text())
     conf.update(TINY)
     conf["engine"] = {"min_bucket": 16}
+    conf.update(conf_extra or {})
     (root / "bench/configs/tiny.json").write_text(json.dumps(conf))
     (root / "bench/traffic/tinymix.json").write_text(json.dumps(mix))
     spec["configs"] = [{"name": "tiny", "source": "smoke widths",
                         "file": "bench/configs/tiny.json", "reduced": [],
                         "why": "CPU test size"}]
     spec["workloads"] = [{"name": workload, "config": "tiny",
-                          "traffic": "tinymix", "chips": 1,
+                          "traffic": "tinymix", "chips": chips,
                           "why": "CPU test size"}]
     for m in spec["end_to_end"] + spec["per_layer"]:
         m.pop("workloads", None)
@@ -60,22 +64,52 @@ def tiny_root(tmp: pathlib.Path, mix=CHAT, limit: float = 0.5,
     return root
 
 
-def drive(monkeypatch, capsys, root: pathlib.Path, *extra, seed=7,
-          seconds="3", workload="tiny.chat"):
-    """Run bench/run.py's main on the CPU through `root`; returns the
-    parsed last stdout line."""
+def off_chip(patch, root: pathlib.Path) -> None:
+    """Point bench/run.py at `root` and skip its look for a chip, its
+    compile cache and the strict check of the tiny configuration, through
+    `patch` (pytest's `monkeypatch.setattr`, or plain `setattr` in a
+    throwaway process)."""
     import jax
 
     from bench import cell, run
 
-    monkeypatch.setattr(run, "ROOT", root)
-    monkeypatch.setattr(run, "BENCH", root / "bench")
-    monkeypatch.setattr(run, "OUT", root / ".bench_out")
-    monkeypatch.setattr(run, "chips_or_exit", lambda n: jax.devices()[:n])
-    monkeypatch.setattr(run, "setup_cache", lambda: "off")
+    patch(run, "ROOT", root)
+    patch(run, "BENCH", root / "bench")
+    patch(run, "OUT", root / ".bench_out")
+    patch(run, "chips_or_exit", lambda n: jax.devices()[:n])
+    patch(run, "setup_cache", lambda: "off")
     strict = cell.model_config
-    monkeypatch.setattr(cell, "model_config",
-                        lambda conf, strict_=True: strict(conf, False))
+    patch(cell, "model_config",
+          lambda conf, strict_=True: strict(conf, False))
+
+
+def in_subprocess(code: str, devices: int = 4, timeout: int = 600) -> str:
+    """Run `code` in a fresh Python that sees `devices` CPU devices (this
+    process keeps its one), with the repo and these helpers importable;
+    returns its stdout."""
+    import os
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(ROOT / "src"), str(ROOT), str(pathlib.Path(__file__).parent),
+         env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=timeout)
+    assert proc.returncode == 0, (
+        f"subprocess failed:\nSTDOUT:\n{proc.stdout}\nSTDERR:\n"
+        f"{proc.stderr[-8000:]}")
+    return proc.stdout
+
+
+def drive(monkeypatch, capsys, root: pathlib.Path, *extra, seed=7,
+          seconds="3", workload="tiny.chat"):
+    """Run bench/run.py's main on the CPU through `root`; returns the
+    parsed last stdout line."""
+    from bench import run
+
+    off_chip(monkeypatch.setattr, root)
     assert run.main(["--workload", workload, "--seed", str(seed),
                      "--seconds", seconds, "--trace", "0", *extra]) == 0
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])

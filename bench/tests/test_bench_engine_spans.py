@@ -106,3 +106,26 @@ def test_reader_is_listed_for_the_batch_cell(name):
     entry = {m["name"]: m for m in cell.load_spec()["per_layer"]}[name]
     assert entry["workloads"] == ["glm4_9b.batch"]
     assert entry["source"] in ("program_span", "program_counter")
+
+
+def test_stalls_in_the_window():
+    """bench/run.py's stall log: the longest `engine.step` inside the
+    window, its longest phase, and the steps over 5x the median."""
+    from bench import run
+
+    clk = Clock()
+    tel = Telemetry(clock=clk)
+    tel.attach(n_slots=2, clock=clk)
+    t = 0.0
+    # a refill (step 2, admitting uid 9) takes 2.0 s, a stall 1.5 s
+    for i, sync in enumerate([0.1] * 2 + [2.0] + [0.1] * 5 + [1.5, 0.1]):
+        decode_step(tel, clk, i, t, t + 0.01, t + 0.01 + sync,
+                    t + 0.02 + sync, admitted=[9] if i == 2 else ())
+        t += 0.02 + sync
+    got = run.stalls(tel, 0.0, t)
+    assert got == {"max_step_ms": "2020.000",
+                   "max_step_phase": "engine.decode.sync",
+                   "max_decode_step_ms": "1520.000",
+                   "median_decode_step_ms": "120.000",
+                   "slow_decode_steps": 1}
+    assert run.stalls(tel, t + 1, t + 2) == {"max_step_ms": "na"}

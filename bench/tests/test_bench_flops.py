@@ -43,3 +43,28 @@ def test_model_ops_by_hand():
     assert flops.matmul_params(CONF) == L * per_layer + d * V
     assert flops.decode_token_ops(CONF, 10) == (
         2 * (L * per_layer + d * V) + 4 * L * H * hd * 10)
+
+
+def test_kernel_calls_per_chip_by_hand():
+    """On a 2 x 2 mesh each of the four chips makes every call on half
+    the rows (model) and half the activation rows (data), reading the
+    whole codebook; the calls of all four are counted."""
+    conf = dict(CONF, mesh={"data": 2, "model": 2})
+    calls = flops.kernel_calls(conf, m=8)
+    assert len(calls) == 4 * 28
+    assert calls[:28] * 4 == calls
+    lo, hi = calls[0], calls[1]
+    # attn.q on one chip: 64 of its 128 rows, 4 of the 8 activation rows
+    assert lo[0] == 2 * 4 * 64 * 96 and hi[0] == 2 * 4 * 64 * 32
+    outl = 13 * 1 + 115 * 0
+    assert lo[1] == pytest.approx(64 * 96 * 2 / 8 + 4 * 96 * 4
+                                  + outl * 96 / 128 / 2 * 8 + 4 * 96 * 2
+                                  + 4 * 64 * 4)
+    assert hi[1] == pytest.approx(64 * 32 * 4 / 8 + 16 * 32 * 4
+                                  + outl * 32 / 128 / 2 * 8 + 4 * 32 * 2
+                                  + 4 * 64 * 4)
+    one = flops.kernel_calls(CONF, m=8)
+    # the operations split exactly; the codebooks and activations each
+    # chip reads are counted on every chip
+    assert sum(c[0] for c in calls) == sum(c[0] for c in one)
+    assert sum(c[1] for c in calls) > sum(c[1] for c in one)

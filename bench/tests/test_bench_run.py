@@ -106,3 +106,41 @@ def test_fault_in_timed_path_is_not_correct(monkeypatch, capsys, root,
     out = drive(monkeypatch, capsys, root)
     assert out["correct"] is False
     assert out["checks"]["mean_logit_gap"]["value"] > TINY_LIMIT
+
+
+# The tiny model with qk-norm reads 7.0e-05 to 1.8e-04 (seeds 7-9); the
+# same runs against a reference that leaves qk-norm out, 0.067 to 0.074.
+@pytest.fixture(scope="module")
+def qk_root(tmp_path_factory):
+    return tiny_root(tmp_path_factory.mktemp("qk"), limit=TINY_LIMIT,
+                     conf_extra={"qk_norm": True})
+
+
+def _reference_without_qk_norm(monkeypatch):
+    """The plain reference with the RMSNorm of q and k left out."""
+    import types
+
+    from bench import cell
+
+    real = cell.reference_for
+
+    def without(family, bench):
+        ref = real(family, bench)
+        return types.SimpleNamespace(
+            logits=lambda conf, *a: ref.logits({**conf, "qk_norm": False},
+                                               *a))
+    monkeypatch.setattr(cell, "reference_for", without)
+
+
+def test_qk_norm_run_is_correct(monkeypatch, capsys, qk_root):
+    out = drive(monkeypatch, capsys, qk_root)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["checks"]["mean_logit_gap"]["value"] <= TINY_LIMIT
+
+
+def test_qk_norm_left_out_of_the_reference_is_not_correct(
+        monkeypatch, capsys, qk_root):
+    _reference_without_qk_norm(monkeypatch)
+    out = drive(monkeypatch, capsys, qk_root)
+    assert out["correct"] is False
+    assert out["checks"]["mean_logit_gap"]["value"] > TINY_LIMIT

@@ -1,5 +1,6 @@
 """bench/weights.py: the on-device CLAQ layout equals what the program's
 own `build_quantized_tensor` makes from the same codes."""
+import functools
 import json
 
 import jax
@@ -93,3 +94,67 @@ def test_seed_past_32_bits_changes_the_weights():
     a = W.raw_matrix(W.base_key(5), lay)["codes"]
     b = W.raw_matrix(W.base_key(5 + 2**32), lay)["codes"]
     assert not np.array_equal(a, b)
+
+
+def test_served_tree_with_qk_norm_is_the_program_tree():
+    """A qk-norm configuration adds the program's per-head norm scales,
+    near 1, one row per layer, in the served dtype as the program's
+    attention block holds them."""
+    from bench import cell
+    from repro.models import api
+
+    conf = json.loads((ROOT / "bench/configs/qwen2_1p5b.json").read_text())
+    conf.update(TINY, qk_norm=True)
+    cfg = cell.model_config(conf, strict=False)
+    want = jax.eval_shape(lambda: api.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+    got = W.served_params(5, conf)
+    for m in ("q_norm", "k_norm"):
+        w = want["blocks"]["attn"][m]["scale"]
+        g = got["blocks"]["attn"][m]["scale"]
+        assert (g.shape, g.dtype) == (w.shape, w.dtype) == (
+            (TINY["num_hidden_layers"], TINY["head_dim"]), jnp.bfloat16)
+        assert 0.5 < float(g.min()) and float(g.max()) < 1.5
+    plain = {k: v for k, v in conf.items() if k != "qk_norm"}
+    assert "q_norm" not in W.served_params(5, plain)["blocks"]["attn"]
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _parents_build(key, mc_items, claq_items):
+    """The one-device build as the benchmark had it before weights could
+    be drawn per chip, kept to show that path unchanged."""
+    mc, claq = dict(mc_items), dict(claq_items)
+    dtype = jnp.bfloat16
+    L = mc["num_hidden_layers"]
+    params = {}
+    for name, (shape, dt) in W.dense_leaf_specs(mc, dtype).items():
+        W._set(params, name, W.raw_dense_leaf(key, name, shape, dt))
+    for m_i, (name, (rows, cols)) in enumerate(W.matrix_shapes(mc).items()):
+        lay = W.layout(rows, cols, claq)
+        qt = jax.lax.map(
+            lambda l, m_i=m_i, lay=lay: W._assemble(
+                W.raw_matrix(W.matrix_key(key, l, m_i), lay), lay),
+            jnp.arange(L))
+        W._set(params, f"blocks.{name}.kernel", qt)
+    return params
+
+
+def test_one_device_build_is_unchanged():
+    """served_params without a mesh is, leaf for leaf and bit for bit, the
+    build the glm4_9b cell has always served (at tiny widths)."""
+    conf = json.loads((ROOT / "bench/configs/glm4_9b.json").read_text())
+    conf.update(TINY, rotary_dim=16)
+    keys = ("num_hidden_layers", "hidden_size", "num_attention_heads",
+            "num_key_value_heads", "head_dim", "intermediate_size",
+            "vocab_size", "attention_bias", "tie_word_embeddings")
+    seed = 2**33 + 17
+    want = _parents_build(W.base_key(seed), tuple((k, conf[k]) for k in keys),
+                          tuple(sorted(conf["claq"].items())))
+    got = W.served_params(seed, conf)
+    flat_w = jax.tree_util.tree_leaves_with_path(want)
+    flat_g = jax.tree_util.tree_leaves_with_path(got)
+    assert [p for p, _ in flat_w] == [p for p, _ in flat_g]
+    for (path, a), (_, b) in zip(flat_w, flat_g):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype), path
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=str(path))

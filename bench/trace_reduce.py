@@ -10,8 +10,10 @@ line of program executions (`XLA Modules`) and a line of operations
 * device busy time: the union of the operation intervals inside it;
 * executions of the engine's jitted steps, by name (`jit__decode_fn`,
   `jit__prefill_fn`, the names `ServingEngine` gives them), each with its
-  device interval and the summed time of the CLAQ kernel's operations
-  inside it;
+  device interval on the first chip and the summed time of the CLAQ
+  kernel's operations inside it on every chip (on a mesh each chip runs
+  the same execution on its share of the work; a step execution on
+  another chip that overlaps none of the first chip's is refused);
 * the longest idle gaps, each labelled by the innermost host span open
   at its middle.
 """
@@ -104,6 +106,34 @@ def host_spans(pd) -> List[Tuple[float, float, str]]:
     return []
 
 
+def _plane_execs(mod_line, ops, w0: float, w1: float) -> List[Exec]:
+    """One chip's step executions inside the window, each with the time of
+    the kernel operations that ran inside it."""
+    kern = sorted((a, b) for a, b, n, _ in ops if is_kernel(n))
+    starts = [a for a, _ in kern]
+    execs = []
+    for n, a, b, _ in _events(mod_line):
+        if b <= w0 or a >= w1:
+            continue
+        kind = next((k for k, rx in STEP_PROGRAMS.items()
+                     if rx.search(n)), "other")
+        i = bisect.bisect_left(starts, a)
+        ks = 0.0
+        while i < len(kern) and kern[i][0] < b:
+            ks += min(kern[i][1], b) - kern[i][0]
+            i += 1
+        execs.append(Exec(kind, n, a, b, ks))
+    return execs
+
+
+def _twin(execs: List[Exec], e: Exec) -> Optional[Exec]:
+    """The first chip's execution that `e`, on another chip, is part of:
+    same program, overlapping, the nearest start."""
+    cands = [x for x in execs if x.name == e.name
+             and x.start < e.end and e.start < x.end]
+    return min(cands, key=lambda x: abs(x.start - e.start), default=None)
+
+
 def reduce(pd, top_n: int = 10) -> Reduced:
     spans = host_spans(pd)
     win = [(a, b) for a, b, n in spans if n == WINDOW_SPAN]
@@ -126,22 +156,22 @@ def reduce(pd, top_n: int = 10) -> Reduced:
         busy.extend(chip)
         for a, b, n, _ in ops:
             op_time[n] = op_time.get(n, 0.0) + (b - a)
-        if plane is not planes[0] or mod_line is None:
+        if mod_line is None:
             continue
-        kern = sorted((a, b) for a, b, n, _ in ops if is_kernel(n))
-        kernel_ops = len(kern)
-        starts = [a for a, _ in kern]
-        for n, a, b, _ in _events(mod_line):
-            if b <= w0 or a >= w1:
-                continue
-            kind = next((k for k, rx in STEP_PROGRAMS.items()
-                         if rx.search(n)), "other")
-            i = bisect.bisect_left(starts, a)
-            ks = 0.0
-            while i < len(kern) and kern[i][0] < b:
-                ks += min(kern[i][1], b) - kern[i][0]
-                i += 1
-            execs.append(Exec(kind, n, a, b, ks))
+        kernel_ops += sum(1 for _, _, n, _ in ops if is_kernel(n))
+        if plane is planes[0]:
+            execs = _plane_execs(mod_line, ops, w0, w1)
+            continue
+        for e in _plane_execs(mod_line, ops, w0, w1):
+            twin = _twin(execs, e)
+            if twin is not None:
+                twin.kernel_s += e.kernel_s
+            elif e.kind in STEP_PROGRAMS:
+                # its kernel time would go uncounted and the roofline read
+                # high: refuse the trace rather than read it
+                raise ValueError(
+                    f"{plane.name}: {e.kind} execution at {e.start:.6f} s "
+                    f"overlaps no {e.name} on {planes[0].name}")
     execs.sort(key=lambda e: e.start)
     top = sorted(op_time.items(), key=lambda kv: -kv[1])[:top_n]
     gaps = idle_gaps(_union(busy), w0, w1, spans, top_n)

@@ -10,7 +10,9 @@ Two layers of code, kept apart on purpose:
   (`bench/reference/dense.py`) dequantizes from them.
 * `served_params` assembles the same draws into the program's storage
   format (`repro.core.quantized.QuantizedTensor`, stacked over layers as
-  `launch.quantize` stacks them) in one jitted call on the device.
+  `launch.quantize` stacks them) in one jitted call: on the default
+  device, or, for a configuration that states a mesh, spread over its
+  chips so that each builds only its share (`_build_sharded`).
 
 Nothing calibrates and nothing runs GPTQ: a served step's cost depends on
 the layout (bit-widths, stripes, outlier planes), not on how the codes were
@@ -26,6 +28,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 # storage cost of one reserved outlier (16-bit value + 16-bit row), the
 # paper's Eq. 5 accounting
@@ -166,6 +169,11 @@ def dense_leaf_specs(mc: Dict, dtype) -> Dict[str, Tuple[tuple, object]]:
            "blocks.ln1.scale": ((L, d), jnp.float32),
            "blocks.ln2.scale": ((L, d), jnp.float32),
            "final_norm.scale": ((d,), jnp.float32)}
+    if mc.get("qk_norm"):
+        # RMSNorm of each q and k head before RoPE (Qwen3), held in the
+        # served dtype as the program's attention block holds it
+        for m in ("q_norm", "k_norm"):
+            out[f"blocks.attn.{m}.scale"] = ((L, mc["head_dim"]), dtype)
     if mc["attention_bias"]:
         for m in ("attn.q", "attn.k", "attn.v"):
             out[f"blocks.{m}.bias"] = ((L, shapes[m][0]), dtype)
@@ -219,35 +227,100 @@ def _set(tree: Dict, dotted: str, value) -> None:
     tree[parts[-1]] = value
 
 
+def _stacks(key, mc: Dict, claq: Dict, layers: jax.Array) -> Dict:
+    """Each quantized matrix kind stacked over `layers`, one layer at a
+    time, every layer from its own key (`matrix_key`)."""
+    out = {}
+    for m_i, (name, (rows, cols)) in enumerate(matrix_shapes(mc).items()):
+        lay = layout(rows, cols, claq)
+        out[name] = jax.lax.map(
+            lambda l, m_i=m_i, lay=lay: _assemble(
+                raw_matrix(matrix_key(key, l, m_i), lay), lay),
+            layers)
+    return out
+
+
 @functools.partial(jax.jit, static_argnums=(1, 2))
 def _build(key, mc_items: tuple, claq_items: tuple):
     mc, claq = dict(mc_items), dict(claq_items)
-    dtype = jnp.bfloat16
-    L = mc["num_hidden_layers"]
     params: Dict = {}
-    for name, (shape, dt) in dense_leaf_specs(mc, dtype).items():
+    for name, (shape, dt) in dense_leaf_specs(mc, jnp.bfloat16).items():
         _set(params, name, raw_dense_leaf(key, name, shape, dt))
-    for m_i, (name, (rows, cols)) in enumerate(matrix_shapes(mc).items()):
-        lay = layout(rows, cols, claq)
-        qt = jax.lax.map(
-            lambda l, m_i=m_i, lay=lay: _assemble(
-                raw_matrix(matrix_key(key, l, m_i), lay), lay),
-            jnp.arange(L))
+    stacks = _stacks(key, mc, claq, jnp.arange(mc["num_hidden_layers"]))
+    for name, qt in stacks.items():
         _set(params, f"blocks.{name}.kernel", qt)
     return params
 
 
-# the keys of a configuration file the weights depend on
+def dense_leaf_spec(name: str, ndim: int, axes) -> P:
+    """How a mesh splits an unquantized leaf over all its chips: the
+    embedding and the head by vocabulary rows, every other leaf along its
+    first axis (the layer axis of a stacked block leaf)."""
+    if name == "lm_head.kernel":
+        return P(None, axes)
+    return P(axes, *[None] * (ndim - 1))
+
+
+def _check_split(mc: Dict, n: int) -> None:
+    sizes = {"num_hidden_layers": mc["num_hidden_layers"],
+             "vocab_size": mc["vocab_size"],
+             "hidden_size": mc["hidden_size"]}
+    uneven = {k: v for k, v in sizes.items() if v % n}
+    if uneven:
+        raise ValueError(f"weights split over {n} chips need these sizes "
+                         f"to divide by {n}: {uneven}")
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _build_sharded(key, mc_items: tuple, claq_items: tuple, mesh):
+    """`_build`'s tree, value for value, spread over every chip of `mesh`:
+    each chip draws its own run of layers of every quantized stack (the
+    same per-layer keys, one layer at a time), and its slice of each
+    unquantized leaf (`dense_leaf_spec`; the counter-based generator
+    gives a slice the values of the whole draw).  No chip holds more
+    than its share; the engine lays the tree out as it serves it."""
+    mc, claq = dict(mc_items), dict(claq_items)
+    axes = tuple(mesh.axis_names)
+    n = mesh.size
+    _check_split(mc, n)
+    params: Dict = {}
+    for name, (shape, dt) in dense_leaf_specs(mc, jnp.bfloat16).items():
+        spec = dense_leaf_spec(name, len(shape), axes)
+        _set(params, name, jax.lax.with_sharding_constraint(
+            raw_dense_leaf(key, name, shape, dt), NamedSharding(mesh, spec)))
+    per_chip = mc["num_hidden_layers"] // n
+
+    def local(key):
+        first = jax.lax.axis_index(axes) * per_chip
+        return _stacks(key, mc, claq, first + jnp.arange(per_chip))
+
+    stacks = jax.shard_map(local, mesh=mesh, in_specs=P(),
+                           out_specs=P(axes))(key)
+    for name, qt in stacks.items():
+        _set(params, f"blocks.{name}.kernel", qt)
+    return params
+
+
+# the keys of a configuration file the weights depend on (`qk_norm` only
+# where the file states it)
 MODEL_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
               "num_key_value_heads", "head_dim", "intermediate_size",
-              "vocab_size", "attention_bias", "tie_word_embeddings")
+              "vocab_size", "attention_bias", "tie_word_embeddings",
+              "qk_norm")
 
 
-def served_params(seed: int, conf: Dict):
+def model_items(conf: Dict) -> tuple:
+    """The configuration file's values the weights depend on, as a static
+    argument of the builders."""
+    return tuple((k, conf[k]) for k in MODEL_KEYS if k in conf)
+
+
+def served_params(seed: int, conf: Dict, mesh=None):
     """The whole served params tree for `seed` and a configuration file's
-    contents, built on the default device in one jitted call (the key is
-    an operand, so every seed reuses one compiled program)."""
-    if conf["family"] != "dense":
-        raise ValueError(f"no weight builder for family {conf['family']!r}")
-    mc = tuple((k, conf[k]) for k in MODEL_KEYS)
-    return _build(base_key(seed), mc, tuple(sorted(conf["claq"].items())))
+    contents, in one jitted call (the key is an operand, so every seed
+    reuses one compiled program): on the default device, or with `mesh`
+    spread over its chips, each building only its share."""
+    mc, claq = model_items(conf), tuple(sorted(conf["claq"].items()))
+    if mesh is None:
+        return _build(base_key(seed), mc, claq)
+    return _build_sharded(base_key(seed), mc, claq, mesh)
